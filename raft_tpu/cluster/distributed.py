@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_tpu.distance.fused_l2_nn import fused_l2_nn_argmin

@@ -34,6 +34,7 @@ from raft_tpu.core.tracing import traced, span
 from raft_tpu.distance.fused_l2_nn import fused_l2_nn_argmin
 from raft_tpu.distance.pairwise import l2_expanded
 from raft_tpu.random.rng import RngState, _as_key
+from raft_tpu.utils.precision import get_precision
 
 
 @dataclasses.dataclass
@@ -68,7 +69,8 @@ def init_plus_plus(key: jax.Array, x: jax.Array, n_clusters: int,
 
     def dist2_to(c):
         c_sq = jnp.sum(c * c)
-        return jnp.maximum(x_sq + c_sq - 2.0 * (xf @ c), 0.0)
+        xc = jnp.matmul(xf, c, precision=get_precision())
+        return jnp.maximum(x_sq + c_sq - 2.0 * xc, 0.0)
 
     min_d2 = dist2_to(xf[first])
 
@@ -119,7 +121,7 @@ def _update_centroids(x, w, labels, n_clusters, old_centroids):
     def tile(args):
         xt, lt, wt = args
         oh = jax.nn.one_hot(lt, n_clusters, dtype=jnp.float32) * wt[:, None]
-        return (jnp.einsum("tk,td->kd", oh, xt,
+        return (jnp.einsum("tk,td->kd", oh, xt, precision=get_precision(),
                            preferred_element_type=jnp.float32),
                 jnp.sum(oh, axis=0))
 
@@ -242,7 +244,7 @@ def _minibatch_loop(x, c0, key, n_clusters: int, batch_size: int,
         _, labels = fused_l2_nn_argmin(xb, c)
         oh = jax.nn.one_hot(labels, n_clusters, dtype=jnp.float32)
         bcount = jnp.sum(oh, axis=0)                      # [k]
-        bsum = jnp.einsum("bk,bd->kd", oh, xb,
+        bsum = jnp.einsum("bk,bd->kd", oh, xb, precision=get_precision(),
                           preferred_element_type=jnp.float32)
         v = v + bcount
         # per-cluster EMA toward the batch mean with rate bcount/v

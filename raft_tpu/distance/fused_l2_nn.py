@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from raft_tpu.core.tracing import traced
+from raft_tpu.ops import pallas_kernels as _pk
 from raft_tpu.utils.precision import get_precision
 
 # Column-tile width of the running-argmin scan: large enough to keep the MXU
@@ -31,13 +32,6 @@ def _dist_block(x, yb, x_sq, yb_sq, sqrt):
         preferred_element_type=jnp.float32)
     d2 = jnp.maximum(d2, 0.0)
     return jnp.sqrt(d2) if sqrt else d2
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 @traced("raft_tpu.fused_l2_nn_argmin")
@@ -59,7 +53,7 @@ def fused_l2_nn_argmin(
     m, d = x.shape
     n = y.shape[0]
     if impl is None:
-        impl = "pallas" if _on_tpu() else "xla"
+        impl = "pallas" if _pk._on_tpu() else "xla"
     if impl == "pallas":
         from raft_tpu.ops import fused_l2_argmin as _pallas_argmin
 

@@ -21,6 +21,7 @@ import jax
 
 from raft_tpu.core.tracing import traced
 from raft_tpu.obs import spans as _obs_spans
+from raft_tpu.ops import pallas_kernels as _pk
 import jax.numpy as jnp
 from jax import lax
 
@@ -30,13 +31,6 @@ def _top_k_signed(scores: jax.Array, k: int, select_min: bool):
         neg_vals, idx = lax.top_k(-scores, k)
         return -neg_vals, idx
     return lax.top_k(scores, k)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 # Pallas route thresholds: wide rows where the running-buffer kernel
@@ -84,12 +78,14 @@ def select_k(
     # algorithm choice (the reference's choose_select_k_algorithm,
     # matrix/detail/select_k-inl.cuh:293): Pallas running-buffer kernel
     # for wide rows / small k on TPU, lax.top_k otherwise
+    kernel_shape = n >= _PALLAS_MIN_LEN and k <= _PALLAS_MAX_K
     if impl is None:
-        impl = (
-            "pallas"
-            if _on_tpu() and n >= _PALLAS_MIN_LEN and k <= _PALLAS_MAX_K
-            else "xla"
-        )
+        impl = "pallas" if _pk._on_tpu() and kernel_shape else "xla"
+    if impl != "pallas" and kernel_shape:
+        # a row the kernel serves best took another engine: say why, so
+        # a kernel that gave way shows up in the fallback counter
+        _obs_spans.count_fallback(
+            "select_k", "off_tpu" if not _pk._on_tpu() else "impl_arg")
     if impl == "pallas":
         _obs_spans.count_dispatch("select_k", "pallas")
         from raft_tpu.ops import select_k_pallas

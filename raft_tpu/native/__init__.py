@@ -10,7 +10,9 @@ when the toolchain is unavailable (``available()`` reports which).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -19,31 +21,42 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "raft_tpu_native.cpp")
-_SO = os.path.join(_HERE, "libraft_tpu_native.so")
+# Portable flags only: a binary tuned to the building CPU (-march=native)
+# can die of an illegal instruction on another host of the same arch.
+_FLAGS = ("-O3", "-shared", "-fPIC", "-fopenmp", "-std=c++17")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-fopenmp",
-        "-std=c++17", _SRC, "-o", _SO,
-    ]
+def _so_path() -> str:
+    """The library's file name is keyed by a hash of the source, the
+    flags and the machine architecture, so only a binary built from
+    this very source, for this architecture, is ever loaded — a stale or
+    foreign build under the same directory has a different name."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(platform.machine().encode())
+    return os.path.join(_HERE, f"libraft_tpu_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    # build to a private name, then rename: concurrent builders (test
+    # workers) never load a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+        subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp], check=True,
+                       capture_output=True, timeout=300)
+        os.replace(tmp, so)
         return True
-    except (subprocess.CalledProcessError, OSError, subprocess.TimeoutExpired):
-        # retry without -march=native / -fopenmp (portability fallback)
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO],
-                check=True, capture_output=True, timeout=300,
-            )
-            return True
-        except Exception:
-            return False
+    except (subprocess.CalledProcessError, OSError,
+            subprocess.TimeoutExpired):
+        if os.path.exists(tmp):
+            os.remove(tmp)
+        return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -53,11 +66,11 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not _build():
-                _build_failed = True
-                return None
-        lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            _build_failed = True
+            return None
+        lib = ctypes.CDLL(so)
         lib.refine_host_f32.restype = ctypes.c_int
         lib.refine_host_f32.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,

@@ -505,7 +505,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> CagraInde
         g = jax.random.normal(jax.random.PRNGKey(params.seed ^ 0x5EED),
                               (x.shape[1], proj_d), jnp.float32)
         r, _ = jnp.linalg.qr(g)
-        x_build = x @ r
+        x_build = jnp.matmul(x, r, precision=get_precision())
     else:
         x_build = x
     with span("knn_graph") as _sp:

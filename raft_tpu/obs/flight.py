@@ -71,7 +71,7 @@ class _LogTail(logging.Handler):
 
 
 def _watchdog_kill_info() -> Optional[Dict[str, Any]]:
-    """Parse the stall-kill sidecar ``tools/run_watchdog.sh`` exports
+    """Parse the stall-kill sidecar an external stall watchdog exports
     via the ``WATCHDOG_KILL_INFO`` env var (a JSON file path the
     watchdog writes just before SIGTERM). None when unset, absent, or
     unparseable — a broken sidecar must never cost the dump."""
@@ -89,9 +89,8 @@ def _watchdog_kill_info() -> Optional[Dict[str, Any]]:
 def _robust_state() -> Dict[str, Any]:
     """Active fault plan (rules + live hit/fire counts) and the recent
     degrade-ladder moves, via ``sys.modules`` — the robust package is
-    never imported FROM a dump path (``bench.py`` spec-loads
-    ``faults``/``retry`` standalone before jax; importing the package
-    route from a signal handler could re-enter a wedged import). {}
+    never imported FROM a dump path (a signal handler must not start a
+    package import). {}
     when nothing robust is loaded or armed — and any failure stays
     silent: folding extras must never cost the dump."""
     out: Dict[str, Any] = {}
@@ -206,7 +205,7 @@ class FlightRecorder:
             out.setdefault(name, body)  # core keys are not overridable
         watchdog = _watchdog_kill_info()
         if watchdog is not None:
-            # why an external supervisor killed us (tools/run_watchdog.sh
+            # why an external supervisor killed us (the watchdog
             # writes its stall-kill reason + elapsed time to the file
             # named by WATCHDOG_KILL_INFO just before SIGTERM) — the
             # dump then says WHY it was killed, not just that it was

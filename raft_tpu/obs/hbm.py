@@ -25,7 +25,7 @@ from typing import Any, Dict, Optional
 
 def device_memory_stats(device: Optional[Any] = None) -> Dict[str, int]:
     """``device.memory_stats()`` with all failure modes collapsed to an
-    empty dict (CPU backend, remote plugins mid-outage, very old jax)."""
+    empty dict (CPU backend, very old jax)."""
     try:
         if device is None:
             import jax

@@ -77,9 +77,9 @@ class DevicePeak:
 
 
 # Published per-chip peaks (dense bf16 matmul, HBM bandwidth). The CPU
-# entry is an explicit PLACEHOLDER — the CI mesh only needs the
-# classification machinery to run, not to be calibrated; rows it
-# produces still carry real flops/bytes from the XLA cost model.
+# entry is an explicit PLACEHOLDER for the CPU backend only — the CI
+# mesh needs the classification machinery to run, not to be calibrated;
+# rows it produces still carry real flops/bytes from the XLA cost model.
 DEVICE_PEAKS: Dict[str, DevicePeak] = {
     "v4": DevicePeak("v4", 275e12, 1228e9),
     "v5e": DevicePeak("v5e", 197e12, 819e9),
@@ -88,33 +88,37 @@ DEVICE_PEAKS: Dict[str, DevicePeak] = {
 }
 
 
-def peak_for_kind(kind: str) -> DevicePeak:
-    """Map a PJRT ``device_kind`` string to its peak entry. Matching is
+def _peak_key(kind: Optional[str]) -> str:
+    """Table key for a PJRT ``device_kind`` string. Matching is
     substring-based over the normalized kind ("TPU v5 lite" and
-    "TPU v5e" both mean v5e); unknown kinds get the CPU placeholder —
-    classification still runs, the ceiling is just not calibrated."""
+    "TPU v5e" both mean v5e); "cpu" is the CPU backend's own kind. Any
+    other kind raises: a device missing from the table has no published
+    peaks, and placeholder ones would mislabel its roofline."""
     k = (kind or "").lower().replace(" ", "")
     if "v5p" in k or "v5pod" in k:
-        return DEVICE_PEAKS["v5p"]
+        return "v5p"
     if "v5e" in k or "v5lite" in k or "v5litepod" in k:
-        return DEVICE_PEAKS["v5e"]
+        return "v5e"
     if "v4" in k:
-        return DEVICE_PEAKS["v4"]
-    return DEVICE_PEAKS["cpu"]
+        return "v4"
+    if k == "cpu":
+        return "cpu"
+    raise ValueError(f"no published peaks for device kind {kind!r}")
+
+
+def peak_for_kind(kind: str) -> DevicePeak:
+    """Map a PJRT ``device_kind`` string to its peak entry (see
+    :func:`_peak_key`; unknown kinds raise ``ValueError``)."""
+    return DEVICE_PEAKS[_peak_key(kind)]
 
 
 def device_peak(device: Optional[Any] = None) -> DevicePeak:
-    """Peak entry for ``device`` (default: device 0). Never raises —
-    a backend that won't even report a device kind degrades to the CPU
-    placeholder."""
-    try:
-        if device is None:
-            import jax
+    """Peak entry for ``device`` (default: device 0)."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        return peak_for_kind(getattr(device, "device_kind", ""))
-    except Exception:
-        return DEVICE_PEAKS["cpu"]
+        device = jax.devices()[0]
+    return peak_for_kind(device.device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,8 +128,8 @@ class InterconnectPeak:
     ``dcn_bw`` the per-host DCN bandwidth share — the two denominators
     of the per-axis comm roofline (``comms.bytes{axis=...}`` / peak).
     Like :class:`DevicePeak` these are order-of-magnitude published
-    figures, not calibrations; the CPU entry is a placeholder so the
-    CI mesh can exercise the classification."""
+    figures, not calibrations; the CPU entry is a placeholder for the
+    CPU backend so the CI mesh can exercise the classification."""
 
     name: str
     ici_bw: float
@@ -148,23 +152,13 @@ INTERCONNECT_PEAKS: Dict[str, InterconnectPeak] = {
 
 def interconnect_peak(kind: Optional[str] = None) -> InterconnectPeak:
     """Interconnect peak entry for a PJRT ``device_kind`` string
-    (default: device 0's kind). Same substring matching and
-    CPU-placeholder degradation as :func:`peak_for_kind`."""
+    (default: device 0's kind). Same matching as :func:`peak_for_kind`:
+    unknown kinds raise."""
     if kind is None:
-        try:
-            import jax
+        import jax
 
-            kind = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:
-            kind = ""
-    k = (kind or "").lower().replace(" ", "")
-    if "v5p" in k or "v5pod" in k:
-        return INTERCONNECT_PEAKS["v5p"]
-    if "v5e" in k or "v5lite" in k or "v5litepod" in k:
-        return INTERCONNECT_PEAKS["v5e"]
-    if "v4" in k:
-        return INTERCONNECT_PEAKS["v4"]
-    return INTERCONNECT_PEAKS["cpu"]
+        kind = jax.devices()[0].device_kind
+    return INTERCONNECT_PEAKS[_peak_key(kind)]
 
 
 def axis_peak_bw(axis: str, peak: Optional[InterconnectPeak] = None

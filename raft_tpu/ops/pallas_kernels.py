@@ -49,10 +49,11 @@ def _pad_to(x, mult, axis, value):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    """Whether the default backend is a TPU: the one place every dispatch
+    decides between a compiled kernel and its off-TPU path. A backend
+    that fails to initialise raises here rather than reading as "not a
+    TPU", which would silently put every kernel in interpret mode."""
+    return jax.devices()[0].platform == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -473,6 +474,11 @@ def pallas_segmented_wanted(kk: int, L: int, d: int, S: int = 128) -> bool:
 
 # Candidates emitted per (segment, query) slot: two best per strided bin.
 LUT_SCAN_BINS = 2 * _LANES
+# Scoped VMEM limit of the LUT-scan kernel. Mosaic's default (16 MiB)
+# is too small for the exact f32 tier: at pq_dim 64 its block-diagonal
+# codebook operand alone is 8 MiB lane-padded, double-buffered, and the
+# v5e compile needs ~34 MiB in all. A v5e core has 128 MiB of VMEM.
+_LUT_SCAN_VMEM_LIMIT = 48 << 20
 
 
 def _lut_scan_config(S: int, K: int, P: int, nb: int, Wb: int,
@@ -512,6 +518,15 @@ def _lane_pick(a: jax.Array, start: int, stride: int, n: int) -> jax.Array:
         return jax.lax.slice(a, (0, start), (1, start + n))
     return jax.lax.slice(a, (0, start),
                          (1, start + (n - 1) * stride + 1), (1, stride))
+
+
+def _ref_lane_pick(ref, start: int, stride: int, n: int) -> jax.Array:
+    """:func:`_lane_pick` of a ``[1, W]`` ref's row. A contiguous window
+    is read from the ref itself: Mosaic rejects broadcasting a lane
+    slice of a loaded single-row vector taken past lane 128."""
+    if stride == 1:
+        return ref[:, pl.ds(start, n)]
+    return _lane_pick(ref[:], start, stride, n)
 
 
 def _roll_lanes(x: jax.Array, sh: int) -> jax.Array:
@@ -585,7 +600,7 @@ def _lut_unpack_filter(fbytes_f, fsel, foff):
     return jax.lax.shift_right_logical(b.astype(jnp.int32), foff) & 1
 
 
-def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
+def _lut_tile_update(code, qv, qc, ids_ref, norms_ref, cbp_ref, t,
                      state, *, metric: str, pq_bits: int, S: int,
                      P: int, G: int, Sg: int, Kc: int, L: int, Rt: int,
                      rot: int, rotp: int, exact: bool, key_bias=None,
@@ -596,9 +611,9 @@ def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
     scan-in-ring kernel runs the identical math per tile.
 
     ``code`` [Rt, G·S] i32 unpacked code values; ``qv`` [rows, rotp]
-    f32 rotated queries; ``qc`` [rows] ⟨q, c⟩; ``ids_row``/``norms_row``
-    [1, G·Rt]; ``cbp_ref`` the grouped block-diagonal codebook operand
-    (indexable per subspace group); ``t`` the code-tile index within
+    f32 rotated queries; ``qc`` [rows] ⟨q, c⟩; ``ids_ref``/``norms_ref``
+    [1, G·Rt] refs of the tile's id / norm rows; ``cbp_ref`` the grouped
+    block-diagonal codebook operand (indexable per subspace group); ``t`` the code-tile index within
     the list (traced or static); ``state`` = (b1k, b1i, b2k, b2i)
     running 2-deep bin values; ``key_bias`` an optional [rows, 1]
     additive key column (the fused ring mode's per-query probe mask —
@@ -615,8 +630,6 @@ def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
     prec = (jax.lax.Precision.HIGHEST if exact
             else jax.lax.Precision.DEFAULT)
     b1k, b1i, b2k, b2i = state
-    one = jnp.asarray(1.0, opd)
-    zero = jnp.asarray(0.0, opd)
     for si in range(slabs):
         for g in range(G):
             # decode this slab's fold group in VMEM: [128, rot]
@@ -632,7 +645,9 @@ def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
                 for kc in range(K // Kc):
                     kidx = (jax.lax.broadcasted_iota(
                         jnp.int32, (_LANES, Kc * Sg), 1) // Sg + kc * Kc)
-                    oh = jnp.where(tiled == kidx, one, zero)
+                    # one-hot built in f32 then narrowed: Mosaic cannot
+                    # relayout an i1 mask straight into packed 16-bit
+                    oh = jnp.where(tiled == kidx, 1.0, 0.0).astype(opd)
                     cbp = jax.lax.slice(
                         cbp_ref[sg], (kc * Kc * Sg, 0),
                         ((kc + 1) * Kc * Sg, Sg * P))
@@ -649,7 +664,7 @@ def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
                 precision=prec,
                 preferred_element_type=jnp.float32)  # [rows, 128] ⟨q, d⟩
             lane0 = G * si * _LANES + g
-            ids_g = _lane_pick(ids_row, lane0, G, _LANES)      # [1, 128]
+            ids_g = _ref_lane_pick(ids_ref, lane0, G, _LANES)   # [1, 128]
             # list position of lane r: G·(t·Rt + si·128 + r) + g — OOB
             # tail lanes of the last tile carry garbage, mask them
             l_pos = (t * Rt + si * _LANES) * G + g \
@@ -661,7 +676,7 @@ def _lut_tile_update(code, qv, qc, ids_row, norms_row, cbp_ref, t,
             if metric == "ip":
                 key = -(qc[:, None] + qd)
             else:  # l2: ‖c+d‖² − 2⟨q, c+d⟩ (caller adds ‖q‖²)
-                norms_g = _lane_pick(norms_row, lane0, G, _LANES)
+                norms_g = _ref_lane_pick(norms_ref, lane0, G, _LANES)
                 key = norms_g - 2.0 * (qc[:, None] + qd)
             key = jnp.where(valid, key, jnp.inf)
             if key_bias is not None:
@@ -775,7 +790,7 @@ def _ivfpq_lut_scan_kernel(seg_list_ref, qv_ref, codes_ref, ids_ref,
         oids_ref[:] = jnp.full_like(oids_ref, -1)
 
     qv = qv_ref[0]                                   # [seg, rotp] f32
-    ctr = ctr_ref[:]                                 # [1, rotp] f32
+    ctr = ctr_ref[0]                                 # [1, rotp] f32
     qc = jnp.sum(qv * ctr, axis=1)                   # [seg] ⟨q, c⟩
 
     # bytes → code values: selection matmul (exact: values ≤ 255 in f32)
@@ -785,7 +800,7 @@ def _ivfpq_lut_scan_kernel(seg_list_ref, qv_ref, codes_ref, ids_ref,
                              off_ref[:], pq_bits, K)
     filt_row = None
     if filtered:
-        fb_f = fbits_ref[:].astype(jnp.int32).astype(jnp.float32)
+        fb_f = fbits_ref[0, 0].astype(jnp.int32).astype(jnp.float32)
         filt_row = _lut_unpack_filter(fb_f, fsel_ref[:], foff_ref[:])
 
     cur_k = keys_ref[0]                              # [seg, 256]
@@ -795,7 +810,7 @@ def _ivfpq_lut_scan_kernel(seg_list_ref, qv_ref, codes_ref, ids_ref,
              jax.lax.slice(cur_k, (0, _LANES), (seg, 2 * _LANES)),
              jax.lax.slice(cur_i, (0, _LANES), (seg, 2 * _LANES)))
     b1k, b1i, b2k, b2i = _lut_tile_update(
-        code, qv, qc, ids_ref[:], norms_ref[:], cbp_ref, t, state,
+        code, qv, qc, ids_ref.at[0], norms_ref.at[0], cbp_ref, t, state,
         metric=metric, pq_bits=pq_bits, S=S, P=P, G=G, Sg=Sg, Kc=Kc,
         L=L, Rt=Rt, rot=rot, rotp=rotp, exact=exact, filt_row=filt_row)
     keys_ref[0] = jnp.concatenate([b1k, b2k], axis=1)
@@ -884,13 +899,22 @@ def ivfpq_lut_scan_topk(seg_list: jax.Array, qv: jax.Array,
     if filtered:
         # pad to WHOLE tiles (0 = filtered): ids/norms tolerate the
         # pipeline's OOB tail because garbage lanes are masked, but a
-        # misread KEEP bit would admit a filtered candidate
-        fbits = _pad_to(filter_bytes, n_t * Fbt, 1, 0)
+        # misread KEEP bit would admit a filtered candidate. One
+        # [1, Fbt] row per tile: a block's last two dims must tile
+        # (8, 128) or span the array's, and Fbt < 128
+        fbits = _pad_to(filter_bytes, n_t * Fbt, 1, 0).reshape(
+            filter_bytes.shape[0], n_t, 1, Fbt)
 
     qvp = _pad_to(qv.astype(jnp.float32), _SUBLANES, 1, 0.0)
     qvp = _pad_to(qvp, _LANES, 2, 0.0)
     segp, rotp = qvp.shape[1], qvp.shape[2]
     ctr = _pad_to(centers_rot.astype(jnp.float32), _LANES, 1, 0.0)
+    # per-list rows ride a unit middle axis: Mosaic needs a block's last
+    # two dims to tile (8, 128) or span the array's, and one list row
+    # per step is a (1, ·) block
+    ids = ids[:, None, :]
+    norms = norms[:, None, :]
+    ctr = ctr[:, None, :]
 
     sel_lo, sel_hi, off_arr, cbp = _lut_scan_operands(
         codebooks, pq_bits, nb, Wb, G, Sg, lut_dtype)
@@ -899,9 +923,9 @@ def ivfpq_lut_scan_topk(seg_list: jax.Array, qv: jax.Array,
     in_specs = [
         pl.BlockSpec((1, segp, rotp), lambda s, t, sl: (s, 0, 0)),
         pl.BlockSpec((1, Rt, Wb), lambda s, t, sl: (sl[s], t, 0)),
-        pl.BlockSpec((1, G * Rt), lambda s, t, sl: (sl[s], t)),
-        pl.BlockSpec((1, G * Rt), lambda s, t, sl: (sl[s], t)),
-        pl.BlockSpec((1, rotp), lambda s, t, sl: (sl[s], 0)),
+        pl.BlockSpec((1, 1, G * Rt), lambda s, t, sl: (sl[s], 0, t)),
+        pl.BlockSpec((1, 1, G * Rt), lambda s, t, sl: (sl[s], 0, t)),
+        pl.BlockSpec((1, 1, rotp), lambda s, t, sl: (sl[s], 0, 0)),
         pl.BlockSpec((Wb, G * S), lambda s, t, sl: (0, 0)),
         pl.BlockSpec((Wb, G * S), lambda s, t, sl: (0, 0)),
         pl.BlockSpec((1, G * S), lambda s, t, sl: (0, 0)),
@@ -913,7 +937,7 @@ def ivfpq_lut_scan_topk(seg_list: jax.Array, qv: jax.Array,
     if filtered:
         fsel, foff = _filter_unpack_operands(G * Rt)
         in_specs += [
-            pl.BlockSpec((1, Fbt), lambda s, t, sl: (sl[s], t)),
+            pl.BlockSpec((1, 1, 1, Fbt), lambda s, t, sl: (sl[s], t, 0, 0)),
             pl.BlockSpec((Fbt, G * Rt), lambda s, t, sl: (0, 0)),
             pl.BlockSpec((1, G * Rt), lambda s, t, sl: (0, 0)),
         ]
@@ -940,6 +964,8 @@ def ivfpq_lut_scan_topk(seg_list: jax.Array, qv: jax.Array,
             jax.ShapeDtypeStruct((n_seg, segp, LUT_SCAN_BINS), jnp.float32),
             jax.ShapeDtypeStruct((n_seg, segp, LUT_SCAN_BINS), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LUT_SCAN_VMEM_LIMIT),
         interpret=interpret,
     )(seg_list.astype(jnp.int32), *operands)
     return keys[:, :seg], kids[:, :seg]
@@ -1238,8 +1264,8 @@ def gather_refine_topk(dataset: jax.Array, queries: jax.Array,
         # and the full array in HBM for the in-kernel id DMA (DMA
         # row addresses must come from scalar memory)
         pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [qf, cand, cand, data]
     scratch = [
@@ -1251,7 +1277,7 @@ def gather_refine_topk(dataset: jax.Array, queries: jax.Array,
         # word DMAs address rows of a 2-D array
         fw = jax.lax.bitcast_convert_type(
             filter_bits, jnp.int32).reshape(-1, 1)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(fw)
         scratch.append(pltpu.VMEM((bq, bc), jnp.int32))
     scratch += [
@@ -1633,7 +1659,7 @@ def ring_topk_merge(vals: jax.Array, ids: jax.Array, k: int,
     kwargs = {}
     if not interpret:
         # the neighbor barrier needs a collective id (real hardware only)
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             collective_id=1)
     out_v, out_i = pl.pallas_call(
         functools.partial(_ring_topk_kernel, k=k, n_dev=n_dev, mc=mc,
@@ -1641,8 +1667,8 @@ def ring_topk_merge(vals: jax.Array, ids: jax.Array, k: int,
                           flow_control=not interpret, splits=splits,
                           outer_axis=outer_axis),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((mc, kpad), lambda: (0, 0)),
@@ -1879,8 +1905,8 @@ def _ring_lut_scan_kernel(cl_smem, ind_hbm, qv_hbm, codes_hbm, ids_hbm,
             bias = bias_col[:, :1]                   # [mc, 1]
             state = (b1k[:], b1i[:], b2k[:], b2i[:])
             nb1k, nb1i, nb2k, nb2i = _lut_tile_update(
-                code, qv, qc, idrow_sl[pl.ds(sl, 1)],
-                nrow_sl[pl.ds(sl, 1)], cbp_ref, tt, state,
+                code, qv, qc, idrow_sl.at[pl.ds(sl, 1)],
+                nrow_sl.at[pl.ds(sl, 1)], cbp_ref, tt, state,
                 metric=metric, pq_bits=pq_bits, S=S, P=P, G=G, Sg=Sg,
                 Kc=Kc, L=L, Rt=Rt, rot=rot, rotp=rotp,
                 exact=cbp_ref.dtype == jnp.float32, key_bias=bias,
@@ -2103,21 +2129,21 @@ def ring_lut_scan_merge(chunk_lists: jax.Array, probe_ind: jax.Array,
     if not interpret:
         # distinct collective id from ring_topk_merge: a fused search
         # and a plain merge must never share a barrier semaphore
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             collective_id=2)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),    # chunk_lists
-        pl.BlockSpec(memory_space=pltpu.ANY),     # probe indicator
-        pl.BlockSpec(memory_space=pltpu.ANY),     # chunk queries
-        pl.BlockSpec(memory_space=pltpu.ANY),     # packed codes
-        pl.BlockSpec(memory_space=pltpu.ANY),     # ids
-        pl.BlockSpec(memory_space=pltpu.ANY),     # norms
-        pl.BlockSpec(memory_space=pltpu.ANY),     # rotated centers
+        pl.BlockSpec(memory_space=pl.ANY),     # probe indicator
+        pl.BlockSpec(memory_space=pl.ANY),     # chunk queries
+        pl.BlockSpec(memory_space=pl.ANY),     # packed codes
+        pl.BlockSpec(memory_space=pl.ANY),     # ids
+        pl.BlockSpec(memory_space=pl.ANY),     # norms
+        pl.BlockSpec(memory_space=pl.ANY),     # rotated centers
     ]
     operands = [chunk_lists.astype(jnp.int32), ind, qvp, packed, ids,
                 norms, ctr]
     if filtered:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # fbytes
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # fbytes
         operands.append(fbits)
     in_specs += [
         pl.BlockSpec((Wb, G * S), lambda: (0, 0)),

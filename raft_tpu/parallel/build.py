@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_tpu.core.errors import expects
@@ -81,6 +81,7 @@ from raft_tpu.parallel.comms import Comms
 from raft_tpu.robust import degrade as _degrade
 from raft_tpu.robust import faults as _faults
 from raft_tpu.robust import retry as _retry
+from raft_tpu.utils.precision import get_precision
 
 
 # ---------------------------------------------------------------------------
@@ -625,7 +626,8 @@ def build_ivf_pq_distributed(dataset, params, mesh: Mesh,
     def encode_rows(xb, lb, lo, hi):
         try:
             codes, norms = _pq._encode_with_norms(
-                xb @ rotation.T, centers_rot, lb, codebooks,
+                jnp.matmul(xb, rotation.T, precision=get_precision()),
+                centers_rot, lb, codebooks,
                 params.codebook_kind)
             return (_pq.pack_bits_np(np.asarray(codes), params.pq_bits),
                     np.asarray(norms))

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from raft_tpu.core.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from raft_tpu.core.tracing import annotate as _annotate
 from raft_tpu.obs import fleet as _fleet
 from raft_tpu.obs import sanitize as _sanitize

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from raft_tpu.core.errors import expects
@@ -54,6 +54,7 @@ from raft_tpu.neighbors import ivf_common as ic
 from raft_tpu.parallel import merge as _merge
 from raft_tpu.parallel.comms import Comms
 from raft_tpu.robust import faults as _faults
+from raft_tpu.utils.precision import get_precision
 
 
 class ShardedIvfPq(flax.struct.PyTreeNode):
@@ -184,6 +185,17 @@ def _gather_trainset(x: jax.Array, mesh: Mesh, axis: str, t: int,
     fn = shard_map(local, mesh=mesh, in_specs=(P(axis, None),),
                    out_specs=P(), check_vma=False)
     return fn(x)
+
+
+def _label_replicated(trainset: jax.Array, centers: jax.Array,
+                      mesh: Mesh) -> jax.Array:
+    """Nearest-center labels of a mesh-replicated ``trainset``. Every
+    device labels its own copy inside ``shard_map``: on a TPU the argmin
+    is a Pallas kernel, and XLA cannot partition a Mosaic call across a
+    mesh by itself."""
+    fn = shard_map(lambda t, c: fused_l2_nn_argmin(t, c)[1], mesh=mesh,
+                   in_specs=(P(), P()), out_specs=P(), check_vma=False)
+    return fn(trainset, centers)
 
 
 # Cross-shard candidate merges route through parallel/merge.py — the
@@ -321,7 +333,7 @@ def _search_fused_ring(index: "ShardedIvfPq", q: jax.Array, k: int,
             pq_bits=index.pq_bits, pq_dim_static=index.pq_dim)
         # probes on replicated operands: identical on every shard
         _, probes = _pq._coarse_probes(local, qp, n_probes, ip_like)
-        q_rot = qp @ rotation.T
+        q_rot = jnp.matmul(qp, rotation.T, precision=get_precision())
         lists, ind = _chunk_unions(
             probes.reshape(n_dev, mc, n_probes), NS)
         qv = q_rot.reshape(n_dev, mc, q_rot.shape[1])
@@ -372,7 +384,8 @@ def _search_fused_ring(index: "ShardedIvfPq", q: jax.Array, k: int,
     if ip_like:
         dists = jnp.where(ri < 0, -jnp.inf, -rv)
     else:
-        q_sq = jnp.sum((q @ index.rotation.T) ** 2, axis=1)
+        q_sq = jnp.sum(jnp.matmul(q, index.rotation.T,
+                                   precision=get_precision()) ** 2, axis=1)
         dists = jnp.maximum(rv + q_sq[:, None], 0.0)
         if mt == DistanceType.L2SqrtExpanded:
             dists = jnp.sqrt(dists)
@@ -442,7 +455,7 @@ def build_ivf_pq(params: _pq.IndexParams, dataset: jax.Array, mesh: Mesh,
     key = jax.random.PRNGKey(params.seed)
     rotation = _pq.make_rotation_matrix(jax.random.fold_in(key, 1),
                                         rot_dim, dim)
-    centers_rot = centers @ rotation.T
+    centers_rot = jnp.matmul(centers, rotation.T, precision=get_precision())
     t = min(shard_n,
             max(int(shard_n * params.kmeans_trainset_fraction),
                 -(-4 * K // n_dev), 256))
@@ -450,8 +463,9 @@ def build_ivf_pq(params: _pq.IndexParams, dataset: jax.Array, mesh: Mesh,
             "trainset too small for pq_bits=%d: %d < %d codebook entries",
             params.pq_bits, t * n_dev, K)
     trainset = _gather_trainset(x, mesh, axis, t, params.seed, n_real)
-    _, tr_labels = fused_l2_nn_argmin(trainset, centers)
-    tr_res = trainset @ rotation.T - centers_rot[tr_labels]
+    tr_labels = _label_replicated(trainset, centers, mesh)
+    tr_res = (jnp.matmul(trainset, rotation.T, precision=get_precision())
+              - centers_rot[tr_labels])
     sub = jnp.transpose(tr_res.reshape(-1, pq_dim, pq_len), (1, 0, 2))
     codebooks = _pq._vmapped_lloyd(sub, K, params.kmeans_n_iters,
                                    jax.random.fold_in(key, 2))
@@ -472,7 +486,7 @@ def build_ivf_pq(params: _pq.IndexParams, dataset: jax.Array, mesh: Mesh,
         _, labels = fused_l2_nn_argmin(xs, centers)
         labels = jnp.where(gid < n_real, labels, n_lists)  # drop pad rows
         safe = jnp.clip(labels, 0, n_lists - 1)
-        x_rot = xs @ rotation.T
+        x_rot = jnp.matmul(xs, rotation.T, precision=get_precision())
         codes = _pq._encode_rows(x_rot, centers_rot, safe, codebooks)
         decoded = _pq._decode_codes(codes, codebooks)
         recon = centers_rot[safe] + decoded

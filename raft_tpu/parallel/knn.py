@@ -15,7 +15,7 @@ from typing import Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 
 from raft_tpu.core.errors import expects
 from raft_tpu.core import ids as _ids

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from raft_tpu.core.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from raft_tpu.core.errors import expects
 from raft_tpu.core import ids as _ids
 from raft_tpu.matrix import select_k as _select_k
@@ -235,12 +235,12 @@ def _ring_merge_fallback(vals, ids, comms, axis, m: int, k: int,
     v3 = v.reshape(n_dev, mc, k)
     i3 = i.reshape(n_dev, mc, k)
     rank = comms.get_rank()
-    c0 = jax.lax.rem(rank + n_dev - 1, n_dev)
+    c0 = (rank + n_dev - 1) % n_dev
     run_v = jax.lax.dynamic_index_in_dim(v3, c0, 0, keepdims=False)
     run_i = jax.lax.dynamic_index_in_dim(i3, c0, 0, keepdims=False)
     for s in range(n_dev - 1):
         run_v, run_i = comms.ring_topk_hop(run_v, run_i)
-        c = jax.lax.rem(rank + 2 * n_dev - s - 2, n_dev)
+        c = (rank + 2 * n_dev - s - 2) % n_dev
         loc_v = jax.lax.dynamic_index_in_dim(v3, c, 0, keepdims=False)
         loc_i = jax.lax.dynamic_index_in_dim(i3, c, 0, keepdims=False)
         cat_v = jnp.concatenate([run_v, loc_v], axis=1)

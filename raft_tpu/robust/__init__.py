@@ -19,9 +19,8 @@ recovery path must be CI-testable instead of outage-tested.
   manifests + encoded-list shards behind
   ``ivf_pq.build_chunked(checkpoint_dir=..., resume=...)``.
 
-``faults`` and ``retry`` are stdlib-only at import: ``bench.py`` loads
-those files standalone before any raft_tpu/jax import (the round-4
-wedged-plugin rule). Everything is inert until a fault plan is
+``faults`` and ``retry`` are stdlib-only at import. Everything is
+inert until a fault plan is
 installed / a retry policy is invoked; fault points cost one None check
 when no plan is active. See docs/developer_guide.md "Robustness".
 """

@@ -46,10 +46,9 @@ env: ``RAFT_TPU_FAULT_PLAN`` (path to a plan file) or
 fault-point hit. Every fire counts
 ``faults.fired{site=...,kind=...}`` when obs recording is on.
 
-Deliberately stdlib-only (no jax, no raft_tpu imports): ``bench.py``
-loads this file standalone before any raft_tpu/jax import (the round-4
-wedged-plugin rule), and counters reach the obs registry only when
-``raft_tpu.obs.spans`` is already imported by someone else.
+Deliberately stdlib-only (no jax, no raft_tpu imports): counters reach
+the obs registry only when ``raft_tpu.obs.spans`` is already imported
+by someone else.
 """
 
 from __future__ import annotations

@@ -12,7 +12,8 @@ gap with the standard production recipe, robustness first:
 - **AOT warmup, provably-zero steady-state recompiles** — ``start()``
   runs every (tenant × bucket × k) shape through the REAL dispatch
   path once, so the jit caches are warm before the first user request;
-  with ``compile_cache_dir`` set the XLA compilation cache persists
+  with ``compile_cache_dir`` set (and ``JAX_COMPILATION_CACHE_DIR``
+  unset, which takes precedence) the XLA compilation cache persists
   across process restarts (bounded cold-start). The PR-3
   ``recompile_budget(0)`` sanitizer wraps steady-state traffic in
   tests/CI — an unexpected retrace is a FAILURE, not a latency blip.
@@ -50,6 +51,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from raft_tpu.core import logging as _log
+from raft_tpu.core.compile_cache import use_compile_cache
 from raft_tpu.obs import sanitize as _sanitize
 from raft_tpu.obs import spans as _spans
 from raft_tpu.obs import trace as _trace
@@ -102,7 +104,8 @@ class ServerConfig:
     (``None`` → unbounded, the offline default). ``compile_cache_dir``
     points jax's persistent compilation cache somewhere durable so a
     restarted server's cold-start is bounded by cache loads, not
-    recompiles."""
+    recompiles; a set ``JAX_COMPILATION_CACHE_DIR`` takes precedence
+    (:func:`raft_tpu.core.compile_cache.use_compile_cache`)."""
 
     max_batch: int = 32
     queue_depth: int = 256
@@ -203,7 +206,13 @@ class MicroBatchServer:
             if self._running:
                 return self
         if self.config.compile_cache_dir:
-            self._persist_compile_cache(self.config.compile_cache_dir)
+            import jax
+
+            use_compile_cache(self.config.compile_cache_dir)
+            # compile times on serving buckets are small; cache every
+            # program rather than only the slow ones
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0)
         if warmup:
             for tenant in self.registry.resident():
                 try:
@@ -297,23 +306,6 @@ class MicroBatchServer:
                 raise
             _log.info("serve: exposition endpoint at %s", self.expo.url)
         return self
-
-    @staticmethod
-    def _persist_compile_cache(cache_dir: str) -> None:
-        """Best-effort persistent XLA compilation cache: a cold-started
-        server reloads compiled buckets from disk instead of
-        recompiling them (bounded cold-start). Failure degrades to
-        in-memory caching — never blocks serving."""
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # compile times on serving buckets are small; cache every
-            # program rather than only the slow ones
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception as e:  # unknown jax: in-memory cache only
-            _log.warn("serve: persistent compile cache unavailable (%s)", e)
 
     def warm_tenant(self, name: str) -> int:
         """AOT-precompile tenant ``name``'s bucket set: run every

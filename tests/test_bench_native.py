@@ -253,3 +253,19 @@ def test_chunked_groundtruth_matches_exact(rng):
     de = np.take_along_axis(d, exact, axis=1)
     np.testing.assert_allclose(np.sort(dg, 1), np.sort(de, 1),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_native_library_keyed_by_source_and_flags(monkeypatch, tmp_path):
+    """Only a binary built from this source with these portable flags is
+    ever loaded: the file name changes with either, so a stale or
+    foreign build sitting in the package directory is never picked up."""
+    assert "-march=native" not in native._FLAGS
+    name = native._so_path()
+    assert os.path.basename(name).startswith("libraft_tpu_native-")
+    monkeypatch.setattr(native, "_FLAGS", native._FLAGS + ("-DCHANGED",))
+    assert native._so_path() != name
+    monkeypatch.undo()
+    src = tmp_path / "raft_tpu_native.cpp"
+    src.write_bytes(open(native._SRC, "rb").read() + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert native._so_path() != name

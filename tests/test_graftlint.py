@@ -259,7 +259,7 @@ def test_gl05_fires_on_lane_tiling_memory_space_and_gather():
 GL06_BAD = """
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from raft_tpu.parallel.comms import Comms
 
 
@@ -280,7 +280,7 @@ def run(x, mesh, axis="shard"):
 
 GL06_GOOD = """
 from jax.sharding import PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from raft_tpu.parallel.comms import Comms
 
 
@@ -315,7 +315,7 @@ def test_gl06_quiet_on_wrapped_and_axis_generic_code():
 
 GL06_TUPLE = """
 from jax.sharding import PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from raft_tpu.parallel.comms import Comms
 from raft_tpu.parallel.mesh import hier_mesh
 
@@ -572,7 +572,7 @@ def test_gl08_factory_overlap_idiom_quiet():
 
 GL09_BAD = """
 from jax.sharding import Mesh, PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 
 
 def local(a, b):
@@ -606,7 +606,7 @@ def test_gl09_fires_on_arity_and_axis_mismatch():
 
 GL09_TUPLE = """
 from jax.sharding import PartitionSpec as P
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from raft_tpu.parallel.mesh import hier_mesh
 
 HIER_AXIS_NAMES = ("dcn", "ici")

@@ -497,7 +497,7 @@ class TestNoOverheadWhenDisabled:
         # no comms.* series appear anywhere, no events recorded
         from jax.sharding import PartitionSpec as P
 
-        from raft_tpu.core.compat import shard_map
+        from jax import shard_map
         from raft_tpu.obs import trace
         from raft_tpu.parallel import Comms, make_mesh
 

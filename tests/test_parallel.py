@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from scipy.spatial.distance import cdist
 
@@ -310,6 +310,22 @@ class TestShardedKnn:
         hits = sum(len(set(g) & set(r)) for g, r in
                    zip(np.asarray(ids), ref_i))
         assert hits / ref_i.size >= 0.99
+
+    def test_replicated_trainset_labels_match_naive(self, mesh, rng):
+        """The sharded IVF-PQ build labels its mesh-replicated trainset
+        inside shard_map; every device's copy gives the naive labels."""
+        from jax.sharding import NamedSharding
+
+        from raft_tpu.parallel import ivf as pivf
+
+        rep = NamedSharding(mesh, P())
+        t = jax.device_put(rng.random((300, 16), dtype=np.float32), rep)
+        c = jax.device_put(rng.random((12, 16), dtype=np.float32), rep)
+        labels = pivf._label_replicated(t, c, mesh)
+        want = np.argmin(cdist(np.asarray(t), np.asarray(c),
+                               "sqeuclidean"), 1)
+        for shard in labels.addressable_shards:
+            np.testing.assert_array_equal(np.asarray(shard.data), want)
 
 
 class TestHierMerge:

@@ -29,24 +29,34 @@ class TestPeakTable:
         assert prof.peak_for_kind("cpu").name == "cpu"
 
     def test_unknown_kind_degrades_to_cpu_placeholder(self):
-        for kind in ("", None, "TPU v9 hyperpod", "gpu"):
-            peak = prof.peak_for_kind(kind)
-            assert peak.name == "cpu" and peak.placeholder
+        # an unknown kind has no peaks: it raises instead of borrowing the
+        # CPU placeholder, which only the CPU backend's own kind gets
+        assert prof.peak_for_kind("cpu").placeholder
+        for kind in ("", None, "TPU v9", "TPU v9 hyperpod", "gpu"):
+            with pytest.raises(ValueError, match="device kind"):
+                prof.peak_for_kind(kind)
+            if kind is not None:  # None means "device 0's kind"
+                with pytest.raises(ValueError, match="device kind"):
+                    prof.interconnect_peak(kind)
 
     def test_ridge_is_flops_over_bw(self):
         for peak in prof.DEVICE_PEAKS.values():
             assert peak.ridge == pytest.approx(peak.flops / peak.hbm_bw)
 
     def test_device_peak_never_raises(self):
-        # real device 0 (CPU mesh) and a broken device object
-        assert prof.device_peak().name in prof.DEVICE_PEAKS
+        # real device 0 (CPU mesh) maps to the CPU entry; a device whose
+        # backend fails to report its kind raises rather than reading as
+        # a CPU
+        assert prof.device_peak().name == "cpu"
+        assert prof.interconnect_peak().name == "cpu"
 
         class Broken:
             @property
             def device_kind(self):
                 raise RuntimeError("backend gone")
 
-        assert prof.device_peak(Broken()).name == "cpu"
+        with pytest.raises(RuntimeError, match="backend gone"):
+            prof.device_peak(Broken())
 
 
 class TestVersionTolerantAccessors:

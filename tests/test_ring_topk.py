@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from raft_tpu.core.compat import shard_map
+from jax import shard_map
 from raft_tpu.obs import sanitize
 from raft_tpu.ops import pallas_kernels as pk
 from raft_tpu.parallel import (

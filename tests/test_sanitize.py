@@ -144,7 +144,7 @@ class TestCollectiveSchedule:
     def test_axis_gated_psum_is_caught(self, mesh):
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from raft_tpu.core.compat import shard_map
+        from jax import shard_map
 
         def prog(x):
             def local(v):
@@ -165,7 +165,7 @@ class TestCollectiveSchedule:
         # device executes a psum regardless of the predicate
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from raft_tpu.core.compat import shard_map
+        from jax import shard_map
 
         def prog(x):
             def local(v):
@@ -182,7 +182,7 @@ class TestCollectiveSchedule:
 
     def test_comms_schedule_recorder(self, mesh):
         from jax.sharding import PartitionSpec as P
-        from raft_tpu.core.compat import shard_map
+        from jax import shard_map
         from raft_tpu.parallel import Comms
 
         comms = Comms("shard")

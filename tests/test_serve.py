@@ -521,6 +521,33 @@ class TestServer:
             np.testing.assert_array_equal(i, i_ref[j])
         assert all(i.shape == (10,) for _, i in got_p)
 
+    @pytest.mark.parametrize("env_dir", [None, "/env/cache"])
+    def test_compile_cache_dir_persists_every_bucket(self, monkeypatch,
+                                                     tmp_path, env_dir):
+        """``compile_cache_dir`` caches every program, however quick its
+        compile, and yields to a set JAX_COMPILATION_CACHE_DIR."""
+        import jax
+
+        old_dir = jax.config.jax_compilation_cache_dir
+        old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        if env_dir is not None:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        srv = serve.MicroBatchServer(
+            serve.IndexRegistry(budget_bytes=1000), serve.ServerConfig(
+                compile_cache_dir=str(tmp_path), default_slo_s=None))
+        try:
+            srv.start(warmup=False)
+            srv.stop()
+            assert jax.config.jax_compilation_cache_dir == (
+                old_dir if env_dir else str(tmp_path))
+            assert jax.config.jax_persistent_cache_min_compile_time_secs \
+                == 0.0
+        finally:
+            jax.config.update("jax_compilation_cache_dir", old_dir)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              old_min)
+
 
 # ---------------------------------------------------------------------------
 # zero steady-state recompiles (the AOT-warmup contract)

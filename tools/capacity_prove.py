@@ -222,7 +222,7 @@ def prove_sharded_merge(n: int = DEFAULT_N, tier: str = "ring") -> dict:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from raft_tpu.core.compat import shard_map
+    from jax import shard_map
     from raft_tpu.core import ids as _ids
     from raft_tpu.obs import sanitize as _san
     from raft_tpu.parallel import merge as _merge
@@ -305,7 +305,7 @@ def prove_build_distributed_pass(n: int = DEFAULT_N,
     from jax.sharding import Mesh, PartitionSpec as P
     from raft_tpu.cluster import kmeans_balanced
     from raft_tpu.cluster.kmeans_balanced import KMeansBalancedParams
-    from raft_tpu.core.compat import shard_map
+    from jax import shard_map
     from raft_tpu.core import ids as _ids
     from raft_tpu.neighbors import ivf_pq as _pq
     from raft_tpu.obs import sanitize as _san

@@ -37,7 +37,7 @@ GL10  facade bypass — raw ``lax.psum``/``all_gather``/``ppermute``/...
 
 Analyses are module-local and conservative: a finding needs a
 statically-resolvable axis/perm/spec; anything dynamic is skipped, so
-axis-generic helpers (``core/compat.axis_size``, the facade itself)
+axis-generic helpers (``jax.lax.axis_size``, the facade itself)
 stay quiet by construction.
 """
 
