@@ -31,9 +31,9 @@ RAFT_TPU_BENCH_BUDGET_S total wall-clock budget. The compile cache
 follows JAX_COMPILATION_CACHE_DIR, else the repo's .cache/jax.
 
 Observability (docs/observability.md): RAFT_TPU_BENCH_OBS=1 runs a few
-diagnostic batches per measured row under raft_tpu.obs (sync + stage
-mode) and adds a per-stage latency breakdown ("stages": mean span
-seconds, incl. ivf_pq.search.{coarse_quantize,lut,scan} and refine),
+diagnostic batches per measured row under raft_tpu.obs (sync mode)
+and adds a per-API latency breakdown ("stages": mean span seconds of
+each traced entry point, e.g. ivf_pq.search and refine),
 "peak_hbm_bytes", p50/p99 search-latency quantiles
 ("latency_p50_s"/"latency_p99_s"/"latency_reps"), and roofline cost
 columns ("flops"/"bytes_accessed"/"arith_intensity"/"bound"/
@@ -309,10 +309,8 @@ def _row(dataset_name, r):
         # RAFT_TPU_BENCH_OBS=1: per-stage span seconds for one diagnostic
         # batch + the allocator's process-lifetime peak-HBM high-water
         # mark (PJRT has no reset, so it includes the build and earlier
-        # rows; None on CPU). stages_path names the program decomposed —
-        # it can differ from the scan mode the timed QPS loop used
+        # rows; None on CPU)
         row["stages"] = r.stage_breakdown
-        row["stages_path"] = getattr(r, "stage_path", None)
         row["peak_hbm_bytes"] = getattr(r, "peak_hbm_bytes", None)
     if getattr(r, "latency_quantiles", None) is not None:
         # p50/p99 of the diagnostic batches (Histogram.quantile bucket

@@ -403,8 +403,9 @@ from raft_tpu.obs import load_jsonl
 
 rows = load_jsonl("/tmp/raft_tpu_obs_smoke.jsonl")
 names = {r["name"] for r in rows}
-need = {"span.ivf_pq.search.coarse_quantize", "span.ivf_pq.search.lut",
-        "span.ivf_pq.search.scan", "span.refine"}
+# the capture times whole-API calls; a span.ivf_pq.search.scan child
+# appears only where the fused Pallas LUT tier runs (a TPU)
+need = {"span.ivf_pq.search", "span.refine"}
 missing = need - names
 assert not missing, f"missing expected spans: {sorted(missing)}"
 assert all(r["sum"] > 0 for r in rows
@@ -1251,22 +1252,21 @@ from raft_tpu.neighbors import ivf_pq
 
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.random((3000, 32), dtype=np.float32))
-idx = ivf_pq.build(x, ivf_pq.IndexParams(
-    n_lists=16, pq_dim=16, seed=0, cache_reconstruction="never"))
-obs.enable(sync=True, stages=True, registry=MetricsRegistry(),
-           events=True)
+obs.enable(sync=True, registry=MetricsRegistry(), events=True)
 try:
+    idx = ivf_pq.build(x, ivf_pq.IndexParams(
+        n_lists=16, pq_dim=16, seed=0, cache_reconstruction="never"))
     ivf_pq.search(idx, x[:64], 10,
                   ivf_pq.SearchParams(n_probes=8, scan_mode="per_query"))
 finally:
     obs.disable()
 n = trace.export_chrome("/tmp/raft_tpu_ci_trace.json")
-assert n >= 4, f"expected staged spans in the trace, got {n} events"
+assert n >= 4, f"expected build stage spans in the trace, got {n} events"
 with open("/tmp/raft_tpu_ci_trace.json") as f:
     doc = json.load(f)
 names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-need = {"ivf_pq.search", "ivf_pq.search.scan", "ivf_pq.search.lut",
-        "ivf_pq.search.coarse_quantize"}
+need = {"ivf_pq.search", "ivf_pq.build", "ivf_pq.build.train",
+        "ivf_pq.build.encode"}
 assert need <= names, f"missing spans in trace: {sorted(need - names)}"
 assert all("ts" in e and "dur" in e and "tid" in e
            for e in doc["traceEvents"] if e["ph"] == "X")

@@ -73,12 +73,10 @@ class BenchResult:
     # for one diagnostic batch, and the allocator's PROCESS-LIFETIME
     # peak-HBM high-water mark read at capture time — PJRT has no reset,
     # so this includes the build and all earlier rows (None on backends
-    # that don't report, e.g. CPU). stage_path names the program the
-    # breakdown decomposed (the staged per_query f32-LUT path), which
-    # may DIFFER from the scan mode the timed QPS loop auto-selected —
-    # the breakdown attributes stages, it does not re-measure the row
+    # that don't report, e.g. CPU). The spans wrap the same whole-API
+    # calls the timed loop dispatched, synced: they attribute time to
+    # the APIs, they do not re-measure the row
     stage_breakdown: Optional[Dict[str, float]] = None
-    stage_path: Optional[str] = None
     peak_hbm_bytes: Optional[int] = None
     # p50/p99 of the diagnostic batches' end-to-end search latency
     # (bucket-interpolated Histogram.quantile over OBS_REPS synced
@@ -257,12 +255,10 @@ def environment_stamp() -> Dict[str, Any]:
 
 def _obs_capture(search_fn, queries, k, sp, batch_size, context):
     """RAFT_TPU_BENCH_OBS=1: run a few diagnostic batches under the
-    observability layer (sync + stage mode → ivf_pq dispatches
-    coarse_quantize/lut/scan as separate synced programs; refine and the
-    other searches report whole-API spans) and return
-    (stage_seconds_by_span, path, peak_hbm_bytes, latency_quantiles).
-    Runs AFTER the timed measurement so the staged dispatch never
-    pollutes QPS. Stage values are PER-BATCH means over the reps; the
+    observability layer in sync mode (every search reports whole-API
+    spans, synced on their outputs) and return (seconds_by_span,
+    peak_hbm_bytes, latency_quantiles, cost_row). Runs AFTER the timed
+    measurement so the synced diagnostics never pollute QPS. Stage values are PER-BATCH means over the reps; the
     quantiles (p50/p99, ``Histogram.quantile`` bucket interpolation)
     come from a ``bench.search_latency_s`` histogram of each rep's
     end-to-end synced call. RAFT_TPU_BENCH_OBS_REPS overrides the rep
@@ -280,13 +276,12 @@ def _obs_capture(search_fn, queries, k, sp, batch_size, context):
     qb = queries[: min(batch_size, queries.shape[0])]
     prev = _spans._state()  # a RAFT_TPU_OBS=1 enable must survive this
     try:
-        # warm-up: the timed QPS loop ran the FUSED search, so the staged
-        # programs are still uncompiled — the first staged call pays
-        # trace+compile and would report seconds of "stage time". Burn it
-        # into a throwaway registry; measure the later calls.
-        obs.enable(sync=True, stages=True, registry=obs.MetricsRegistry())
+        # warm-up: the diagnostic batch may be a shape the timed loop
+        # never ran, whose first call pays trace+compile. Burn it into
+        # a throwaway registry; measure the later calls.
+        obs.enable(sync=True, registry=obs.MetricsRegistry())
         jax.block_until_ready(search_fn(qb, k, dict(sp)))
-        obs.enable(sync=True, stages=True, registry=reg)
+        obs.enable(sync=True, registry=reg)
         # denser-than-default buckets: the quantile estimate is bucket-
         # interpolated, and the default decade buckets would clamp a
         # handful of similar reps straight to min/max
@@ -328,17 +323,11 @@ def _obs_capture(search_fn, queries, k, sp, batch_size, context):
     stages = {name[len("span."):]: round(h["mean"], 6)
               for name, h in snap["histograms"].items()
               if name.startswith("span.")}
-    # which program the breakdown decomposed: ivf_pq with stage spans
-    # means the staged per_query f32-LUT path ran (possibly different
-    # from the scan mode the timed loop used); otherwise spans wrapped
-    # the same whole-API calls the timed loop dispatched
-    path = ("staged_per_query_f32lut"
-            if any(n.count(".") >= 2 for n in stages) else "whole_api")
     peak = snap["gauges"].get("hbm.peak_bytes")
     jsonl = os.environ.get("RAFT_TPU_BENCH_OBS_JSONL")
     if jsonl:
         reg.dump_jsonl(jsonl, extra={"context": context})
-    return stages, path, (int(peak) if peak else None), quantiles, cost_row
+    return stages, (int(peak) if peak else None), quantiles, cost_row
 
 
 def _xprof_capture(search_fn, queries, k, sp, batch_size, xprof_dir):
@@ -571,10 +560,10 @@ def _run_one_index(index_cfg, algo, dsx, data, queries, k, batch_size,
             ids, dt, qps = _bench_search(leg_fn, q_leg, k, sp, row_bs,
                                          fence_per_call=fenced)
             rec = ds_mod.recall(ids, gt[: q_leg.shape[0]])
-            stages = stage_path = peak_hbm = latency_q = cost_row = None
+            stages = peak_hbm = latency_q = cost_row = None
             if _env_flag("RAFT_TPU_BENCH_OBS"):
                 try:
-                    stages, stage_path, peak_hbm, latency_q, cost_row = \
+                    stages, peak_hbm, latency_q, cost_row = \
                         _obs_capture(
                             leg_fn, q_leg, k, sp, row_bs,
                             context=f"{index_cfg.get('name', algo)} {sp}")
@@ -597,7 +586,7 @@ def _run_one_index(index_cfg, algo, dsx, data, queries, k, batch_size,
             dataset=data.name, k=k, batch_size=row_bs,
             build_s=build_s, search_s=dt, qps=qps, recall=rec,
             build_param=bp, search_param=sp_rec,
-            stage_breakdown=stages, stage_path=stage_path,
+            stage_breakdown=stages,
             peak_hbm_bytes=peak_hbm, latency_quantiles=latency_q,
             fence_per_call=fenced, cost=cost_row,
             env=environment_stamp(),

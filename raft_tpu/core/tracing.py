@@ -15,13 +15,22 @@ attached is negligible (a context-manager enter/exit per call), and the
 XLA metadata is baked in at trace time only.
 
 When the observability layer is enabled (:func:`raft_tpu.obs.enable`),
-``traced`` additionally opens a recording :func:`span` named after the
-API (``raft_tpu.`` prefix stripped), so every traced entry point's wall
-time lands in the metrics registry and nested stage spans report under
-dotted names like ``ivf_pq.search.scan``. In sync mode the function's
-outputs are attached, so the span measures device time. With
-observability off this adds one flag check per call — no clock reads,
-no sync points.
+or a profiler collects, ``traced`` additionally opens a :func:`span`
+named after the API (``raft_tpu.`` prefix stripped), so every traced
+entry point's wall time lands in the metrics registry and nested stage
+spans report under dotted names like ``ivf_pq.build.train`` — on the
+profiler's timeline as ``raft_tpu.ivf_pq.build.train``. The entry
+point's own annotation is the ``TraceAnnotation`` above, so its span
+opens none of its own. In sync mode the function's outputs are
+attached, so the span measures device time. With observability off and
+no profiler collecting this adds two flag checks per call — no clock
+reads, no sync points.
+
+Inside a jitted search program, :func:`annotate` names each stage
+(``raft_tpu.brute_force.distance``, ``raft_tpu.ivf_pq.scan``): it
+changes only the ``op_name`` metadata of the compiled program, so a
+device trace splits the program's time by stage at no cost to the
+program.
 """
 
 from __future__ import annotations
@@ -37,11 +46,15 @@ from raft_tpu.obs.spans import span  # noqa: F401  (re-export: the stage timer)
 
 def annotate(name: str):
     """Named-scope annotation for code that is ALREADY inside a trace
-    (shard_map/jit bodies — the collectives in ``parallel/comms.py``):
-    labels the lowered XLA ops so they group under ``name`` in
-    XProf/Perfetto op profiles. The host-side halves of :func:`traced`
-    (TraceAnnotation, recording spans) are meaningless there — a traced
-    body runs once at trace time — so this is just the metadata half."""
+    (shard_map/jit bodies — the collectives in ``parallel/comms.py``,
+    the stages of the search programs): labels the lowered XLA ops so
+    they group under ``name`` in XProf/Perfetto op profiles and in the
+    ``op_name`` of the compiled program's instructions. Works as a
+    context manager and as a decorator of a function traced under jit.
+    A scope opened outside a jitted function does not reach its ops.
+    The host-side halves of :func:`traced` (TraceAnnotation, recording
+    spans) are meaningless there — a traced body runs once at trace
+    time — so this is just the metadata half."""
     return jax.named_scope(name)
 
 
@@ -68,9 +81,9 @@ def traced(name: Optional[str] = None) -> Callable:
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             with jax.profiler.TraceAnnotation(label), jax.named_scope(label):
-                if not _spans.enabled():
+                if not (_spans.enabled() or _spans.profiling()):
                     return fn(*args, **kwargs)
-                with span(span_name) as sp:
+                with span(span_name, timeline=False) as sp:
                     out = fn(*args, **kwargs)
                     sp.attach(out)
                     return out

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from raft_tpu.core.errors import expects
-from raft_tpu.core.tracing import traced
+from raft_tpu.core.tracing import annotate, traced
 from raft_tpu.core import ids as _ids
 from raft_tpu.distance import pairwise_distance, resolve_metric, DistanceType, SELECT_MIN
 from raft_tpu.matrix import select_k as _select_k
@@ -37,6 +37,15 @@ _TILE_BUDGET_ELEMS = 1 << 26
 
 # Strided-bin width of the per-tile candidate cut (lane-shaped).
 _BIN_LANES = 128
+
+# Stage scopes of the search program (op_name metadata only): the
+# distance contraction with its norms, the per-tile candidate cut, the
+# running merge, and the per-call layout work around them. The
+# single-block select keeps select_k's own scope.
+_DISTANCE = "raft_tpu.brute_force.distance"
+_TILE_CUT = "raft_tpu.brute_force.tile_cut"
+_MERGE = "raft_tpu.brute_force.merge"
+_PREPARE = "raft_tpu.brute_force.prepare"
 
 
 def _two_best_per_bin(dists: jax.Array, select_min: bool):
@@ -182,7 +191,8 @@ def knn(
     if filter_bitset is not None:
         from raft_tpu.core import bitset as _bitset
 
-        fmask = _bitset.to_mask(filter_bitset, n)
+        with annotate(_PREPARE):
+            fmask = _bitset.to_mask(filter_bitset, n)
 
     def _finalize(vals, ids):
         """With a filter, fewer than k candidates may survive: the inf
@@ -190,20 +200,24 @@ def knn(
         same pad convention the IVF searches use)."""
         if fmask is None:
             return vals, ids
-        bad = jnp.isinf(vals)
-        return vals, jnp.where(bad, -1, ids)
+        with annotate(_PREPARE):
+            bad = jnp.isinf(vals)
+            return vals, jnp.where(bad, -1, ids)
 
     if fast:
-        q = queries.astype(jnp.float32)
-        q_sq = jnp.sum(q * q, axis=1)
-        db = index.dataset.astype(jnp.float32)
-        db_sq = index.norms if index.norms is not None else jnp.sum(db * db, axis=1)
+        with annotate(_DISTANCE):
+            q = queries.astype(jnp.float32)
+            q_sq = jnp.sum(q * q, axis=1)
+            db = index.dataset.astype(jnp.float32)
+            db_sq = (index.norms if index.norms is not None
+                     else jnp.sum(db * db, axis=1))
 
         if it >= n:
-            dists = _expanded_block(q, db, q_sq, db_sq, mt)
-            if fmask is not None:
-                dists = jnp.where(fmask[None, :], dists,
-                                  jnp.inf if select_min else -jnp.inf)
+            with annotate(_DISTANCE):
+                dists = _expanded_block(q, db, q_sq, db_sq, mt)
+                if fmask is not None:
+                    dists = jnp.where(fmask[None, :], dists,
+                                      jnp.inf if select_min else -jnp.inf)
             return _finalize(*_select_k(dists, k, select_min=select_min))
 
         # scan over index tiles with a running top-k merge — never holds the
@@ -211,10 +225,11 @@ def knn(
         n_tiles = -(-n // it)
         pad = n_tiles * it - n
         pad_val = jnp.inf if select_min else -jnp.inf
-        dbp = jnp.pad(db, ((0, pad), (0, 0)))
-        dbp_sq = jnp.pad(db_sq, (0, pad), constant_values=pad_val)
-        db_blocks = dbp.reshape(n_tiles, it, d)
-        sq_blocks = dbp_sq.reshape(n_tiles, it)
+        with annotate(_PREPARE):
+            dbp = jnp.pad(db, ((0, pad), (0, 0)))
+            dbp_sq = jnp.pad(db_sq, (0, pad), constant_values=pad_val)
+            db_blocks = dbp.reshape(n_tiles, it, d)
+            sq_blocks = dbp_sq.reshape(n_tiles, it)
         kk = min(k, it)
         # the depth-2 strided-bin cut needs k ≤ 2·bins per tile and a
         # lane-aligned tile; it replaces a per-tile k-extraction select
@@ -223,29 +238,34 @@ def knn(
         use_bins = (impl != "sort" and it % _BIN_LANES == 0
                     and kk <= 2 * _BIN_LANES)
 
-        if fmask is not None:
-            fmask_blocks = jnp.pad(fmask, (0, pad)).reshape(n_tiles, it)
-        else:
-            fmask_blocks = jnp.ones((n_tiles, it), jnp.bool_)
+        with annotate(_PREPARE):
+            if fmask is not None:
+                fmask_blocks = jnp.pad(fmask, (0, pad)).reshape(n_tiles, it)
+            else:
+                fmask_blocks = jnp.ones((n_tiles, it), jnp.bool_)
 
         def step(carry, inp):
             best_v, best_i = carry
             db_blk, sq_blk, base, mask_blk = inp
-            dists = _expanded_block(q, db_blk, q_sq, sq_blk, mt)
-            dists = jnp.where(mask_blk[None, :], dists, pad_val)
-            if use_bins:
-                # EXACT unless ≥3 of a query's true top-k collide in one
-                # of the 128 stride bins of one tile (p ≈ 2e-6 per query
-                # at k=10; impl="sort" forces the guaranteed path). Bin
-                # positions resolve arithmetically — no gathers.
-                tv, ti = _two_best_per_bin(dists, select_min)
-            else:
-                tv, ti = _select_k(dists, kk, select_min=select_min)
-            ti = ti.astype(idt) + base
-            cat_v = jnp.concatenate([best_v, tv], axis=1)
-            cat_i = jnp.concatenate([best_i, ti], axis=1)
-            nv, pos = _top_k_merge(cat_v, k, select_min)
-            ni = jnp.take_along_axis(cat_i, pos, axis=1)
+            with annotate(_DISTANCE):
+                dists = _expanded_block(q, db_blk, q_sq, sq_blk, mt)
+                dists = jnp.where(mask_blk[None, :], dists, pad_val)
+            with annotate(_TILE_CUT):
+                if use_bins:
+                    # EXACT unless ≥3 of a query's true top-k collide in
+                    # one of the 128 stride bins of one tile (p ≈ 2e-6
+                    # per query at k=10; impl="sort" forces the
+                    # guaranteed path). Bin positions resolve
+                    # arithmetically — no gathers.
+                    tv, ti = _two_best_per_bin(dists, select_min)
+                else:
+                    tv, ti = _select_k(dists, kk, select_min=select_min)
+            with annotate(_MERGE):
+                ti = ti.astype(idt) + base
+                cat_v = jnp.concatenate([best_v, tv], axis=1)
+                cat_i = jnp.concatenate([best_i, ti], axis=1)
+                nv, pos = _top_k_merge(cat_v, k, select_min)
+                ni = jnp.take_along_axis(cat_i, pos, axis=1)
             return (nv, ni), None
 
         # global ids = tile base + in-tile position: the bases (and the
@@ -253,19 +273,21 @@ def knn(
         # base values reach n, which overflows int32 past 2³¹ rows even
         # though every in-tile position fits it
         idt = _ids.id_dtype(n)
-        init_v = jnp.full((m, k), pad_val, jnp.float32)
-        init_i = jnp.zeros((m, k), idt)
-        bases = jnp.arange(n_tiles, dtype=idt) * it
+        with annotate(_PREPARE):
+            init_v = jnp.full((m, k), pad_val, jnp.float32)
+            init_i = jnp.zeros((m, k), idt)
+            bases = jnp.arange(n_tiles, dtype=idt) * it
         (vals, idx), _ = lax.scan(
             step, (init_v, init_i), (db_blocks, sq_blocks, bases, fmask_blocks))
         return _finalize(vals, idx)
 
     # general metrics: full pairwise (row-tiled internally) + select
-    dists = pairwise_distance(queries, index.dataset, metric=mt,
-                              metric_arg=index.metric_arg)
-    if fmask is not None:
-        dists = jnp.where(fmask[None, :], dists,
-                          jnp.inf if select_min else -jnp.inf)
+    with annotate(_DISTANCE):
+        dists = pairwise_distance(queries, index.dataset, metric=mt,
+                                  metric_arg=index.metric_arg)
+        if fmask is not None:
+            dists = jnp.where(fmask[None, :], dists,
+                              jnp.inf if select_min else -jnp.inf)
     return _finalize(*_select_k(dists, k, select_min=select_min))
 
 

@@ -51,6 +51,18 @@ import jax.numpy as jnp
 SEGMENT_SIZE = 128
 
 
+def note_rows_dropped(index: str, n: int) -> None:
+    """Count ``n`` rows a build could not hold in its capped lists (a
+    list overflow, or every spill choice full) under the obs counter
+    ``ivf.build.rows_dropped{index=...}``, where the build warns.
+    Recorded only when obs is on (the ``count_dispatch`` convention)."""
+    from raft_tpu.obs import spans as _obs_spans
+
+    if n and _obs_spans.enabled():
+        _obs_spans.registry().inc("ivf.build.rows_dropped", float(n),
+                                  labels={"index": index})
+
+
 def sharded_dispatch(index, mesh, cls_name: str):
     """The pod-scale dispatch gate shared by the IVF search entries:
     returns the ``raft_tpu.parallel.ivf`` module when ``(index, mesh)``

@@ -37,7 +37,7 @@ import numpy as np
 from jax import lax
 
 from raft_tpu.core.errors import expects
-from raft_tpu.core.tracing import traced, span
+from raft_tpu.core.tracing import annotate, traced, span
 from raft_tpu.core import ids as _ids
 from raft_tpu.core import serialize as ser
 from raft_tpu.cluster import kmeans_balanced
@@ -260,6 +260,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfFlatIn
                 _log.warn("ivf_flat: %d rows overflowed every spill choice "
                           "at cap %d (raise list_size_cap_factor)",
                           n_marker, max_list_size)
+                ic.note_rows_dropped("ivf_flat", n_marker)
         else:
             labels = kmeans_balanced.predict(centers, x.astype(jnp.float32),
                                              km_params)
@@ -290,6 +291,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfFlatIn
         _log.warn("ivf_flat: dropped %d overflow vectors (raise "
                   "list_size_cap_factor%s)", n_drop,
                   "" if params.spill else " or set spill=True")
+        ic.note_rows_dropped("ivf_flat", n_drop)
     norms = jnp.sum(packed.astype(jnp.float32) ** 2, axis=-1)
     index = IvfFlatIndex(centers=centers, packed_data=packed,
                          packed_ids=ids, packed_norms=norms,
@@ -379,6 +381,13 @@ def extend(index: IvfFlatIndex, new_vectors: jax.Array,  # graftlint: disable-fn
 # search
 # ---------------------------------------------------------------------------
 
+# Stage scopes of the search programs (op_name metadata only): the whole
+# program is the scan of the probed lists with its merge, and probe
+# selection inside it is its own stage.
+_COARSE = "raft_tpu.ivf_flat.coarse_quantize"
+_SCAN = "raft_tpu.ivf_flat.scan"
+
+
 def _coarse_distances(q, centers, mt):
     """Query→center scores for probe selection (reference:
     detail/ivf_flat_search.cuh select_clusters gemm)."""
@@ -397,6 +406,7 @@ def _coarse_distances(q, centers, mt):
 
 
 @partial(jax.jit, static_argnames=("k", "n_probes", "query_tile"))
+@annotate(_SCAN)
 def _search_impl(index: IvfFlatIndex, queries: jax.Array, k: int,
                  n_probes: int, query_tile: int, filter_bits=None):
     mt = resolve_metric(index.metric)
@@ -406,8 +416,9 @@ def _search_impl(index: IvfFlatIndex, queries: jax.Array, k: int,
     sqrt_out = mt == DistanceType.L2SqrtExpanded
     select_min = mt != DistanceType.InnerProduct
 
-    coarse, coarse_min = _coarse_distances(q_all, index.centers, mt)
-    _, probes = _select_k(coarse, n_probes, select_min=coarse_min)
+    with annotate(_COARSE):
+        coarse, coarse_min = _coarse_distances(q_all, index.centers, mt)
+        _, probes = _select_k(coarse, n_probes, select_min=coarse_min)
 
     def search_tile(args):
         q, probe = args  # [t, dim], [t, P]
@@ -461,6 +472,7 @@ def _search_impl(index: IvfFlatIndex, queries: jax.Array, k: int,
 @partial(jax.jit, static_argnames=("k", "n_probes", "seg", "n_seg",
                                    "seg_chunk", "use_pallas", "select_impl",
                                    "select_recall", "use_segk"))
+@annotate(_SCAN)
 def _search_grouped(index: IvfFlatIndex, queries: jax.Array, k: int,
                     n_probes: int, seg: int, n_seg: int, seg_chunk: int,
                     use_pallas: bool = False, filter_bits=None,
@@ -486,8 +498,9 @@ def _search_grouped(index: IvfFlatIndex, queries: jax.Array, k: int,
     select_min = not ip
     invalid = -jnp.inf if ip else jnp.inf
 
-    coarse, coarse_min = _coarse_distances(q_all, index.centers, mt)
-    _, probes = _select_k(coarse, n_probes, select_min=coarse_min)
+    with annotate(_COARSE):
+        coarse, coarse_min = _coarse_distances(q_all, index.centers, mt)
+        _, probes = _select_k(coarse, n_probes, select_min=coarse_min)
     seg_list, seg_q, pair_seg, pair_slot = ic.segment_probes(
         probes, n_lists, seg, n_seg)
 

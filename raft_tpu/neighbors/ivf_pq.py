@@ -44,7 +44,7 @@ import numpy as np
 from jax import lax
 
 from raft_tpu.core.errors import expects
-from raft_tpu.core.tracing import traced, span
+from raft_tpu.core.tracing import annotate, traced, span
 from raft_tpu.core import ids as _ids
 from raft_tpu.core import serialize as ser
 from raft_tpu.obs import index_stats as _istats
@@ -818,6 +818,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfPqInde
                 _log.warn("ivf_pq: %d rows overflowed every spill choice at "
                           "cap %d (raise list_size_cap_factor)",
                           n_marker, max_list_size)
+                ic.note_rows_dropped("ivf_pq", n_marker)
         else:
             labels = kmeans_balanced.predict(centers, x, km)
             # histogram on host: the [n] labels transfer is small, and a
@@ -847,6 +848,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfPqInde
         from raft_tpu.core import logging as _log
         _log.warn("ivf_pq: dropped %d overflow vectors (raise "
                   "list_size_cap_factor)", n_drop)
+        ic.note_rows_dropped("ivf_pq", n_drop)
     index = IvfPqIndex(
         centers=centers, centers_rot=centers_rot, rotation=rotation,
         codebooks=codebooks, packed_codes=packed,
@@ -1183,7 +1185,11 @@ def build_chunked(dataset, params: Optional[IndexParams] = None,  # graftlint: d
                            "chunks_done": -(-n // chunk_rows)})
     if dropped:
         from raft_tpu.core import logging as _log
+        from raft_tpu.neighbors import ivf_common as ic
+
         _log.warn("ivf_pq chunked build: dropped %d overflow vectors", dropped)
+        # the spill-marker rows are among them: keep excludes both
+        ic.note_rows_dropped("ivf_pq", dropped)
 
     fold = (nbytes < 128 and packed.nbytes > (1 << 30)
             and (L * nbytes) % 128 == 0)
@@ -1383,6 +1389,15 @@ def extend(index: IvfPqIndex, new_vectors: jax.Array,  # graftlint: disable-fn=G
 # search
 # ---------------------------------------------------------------------------
 
+# Stage scopes of the search programs (op_name metadata only): the whole
+# program is the scan of the probed lists with its merge; probe
+# selection, and the query's rotation and lookup table where the
+# program builds one outside the scan kernel, are stages inside it.
+_COARSE = "raft_tpu.ivf_pq.coarse_quantize"
+_LUT = "raft_tpu.ivf_pq.lut"
+_SCAN = "raft_tpu.ivf_pq.scan"
+
+
 def _qd_from_qlut(idx: jax.Array, qlut: jax.Array) -> jax.Array:
     """⟨q,d⟩ per candidate from the query-only LUT: ``idx`` [t, C, S]
     i32 code values, ``qlut`` [t, S, K] → [t, C] f32. One-hot MXU
@@ -1405,8 +1420,8 @@ def _finish_candidates(dots, cand_ids, cand_norms, q_sq, mt, k,
                        filter_bits=None):
     """Shared candidate epilogue: ``dots`` = ⟨q, c+d⟩ per candidate (from
     the LUT decomposition or the recon gather) → metric distances, mask,
-    select, id gather, cosine flip. Used by both the fused per_query
-    path and the stage-decomposed scan, so their results cannot drift."""
+    select, id gather, cosine flip. Used by both the per_query path and
+    the Pallas LUT tier, so their results cannot drift."""
     ip_like = mt in (DistanceType.InnerProduct, DistanceType.CosineExpanded)
     if ip_like:
         dists = dots
@@ -1452,6 +1467,7 @@ def _coarse_probes(index: IvfPqIndex, q_all: jax.Array, n_probes: int,
 
 @partial(jax.jit, static_argnames=("k", "n_probes", "query_tile",
                                    "lut_dtype"))
+@annotate(_SCAN)
 def _search_impl(index: IvfPqIndex, queries: jax.Array, k: int,
                  n_probes: int, query_tile: int, filter_bits=None,
                  lut_dtype: str = "float32"):
@@ -1467,11 +1483,14 @@ def _search_impl(index: IvfPqIndex, queries: jax.Array, k: int,
     ip_like = mt in (DistanceType.InnerProduct, DistanceType.CosineExpanded)
 
     # qc itself is needed regardless — the ⟨q,c⟩ term of the decomposition
-    qc, probes = _coarse_probes(index, q_all, n_probes, ip_like)
+    with annotate(_COARSE):
+        qc, probes = _coarse_probes(index, q_all, n_probes, ip_like)
+        qc_probed_all = jnp.take_along_axis(qc, probes, axis=1)  # [m, P]
 
-    q_rot_all = jnp.matmul(q_all, index.rotation.T, precision=get_precision())
-    q_sq_all = jnp.sum(q_rot_all * q_rot_all, axis=1)
-    qc_probed_all = jnp.take_along_axis(qc, probes, axis=1)  # [m, P] ⟨q,c⟩
+    with annotate(_LUT):
+        q_rot_all = jnp.matmul(q_all, index.rotation.T,
+                               precision=get_precision())
+        q_sq_all = jnp.sum(q_rot_all * q_rot_all, axis=1)
 
     # recon-dot preempts the LUT scan only when (a) the cache exists,
     # (b) the user didn't ask for LUT quantization, and (c) the one-hot
@@ -1511,9 +1530,10 @@ def _search_impl(index: IvfPqIndex, queries: jax.Array, k: int,
         if per_cluster:
             # LUT is per (query, probed cluster): ⟨q_s, cb[probe][k]⟩
             cb_probed = index.codebooks[probe]            # [t, Pr, K, P]
-            lut = jnp.einsum("tsp,tjkp->tjsk", q_sub, cb_probed,
-                             precision=get_precision())   # [t, Pr, S, K]
-            lut = _quantize_lut(lut, lut_dtype)
+            with annotate(_LUT):
+                lut = jnp.einsum("tsp,tjkp->tjsk", q_sub, cb_probed,
+                                 precision=get_precision())  # [t, Pr, S, K]
+                lut = _quantize_lut(lut, lut_dtype)
             if jax.default_backend() == "cpu":
                 # CPU XLA won't fuse the 5-D one-hot — gather instead
                 codes_t = jnp.transpose(codes, (0, 1, 3, 2))  # [t, Pr, S, L]
@@ -1530,9 +1550,10 @@ def _search_impl(index: IvfPqIndex, queries: jax.Array, k: int,
                                 ).reshape(t, n_probes * L)
         else:
             # query-only LUT: ⟨q_s, cb[s,k]⟩ — one batched MXU contraction
-            qlut = jnp.einsum("tsp,skp->tsk", q_sub, index.codebooks,
-                              precision=get_precision())  # [t, S, K]
-            qlut = _quantize_lut(qlut, lut_dtype)
+            with annotate(_LUT):
+                qlut = jnp.einsum("tsp,skp->tsk", q_sub, index.codebooks,
+                                  precision=get_precision())  # [t, S, K]
+                qlut = _quantize_lut(qlut, lut_dtype)
             idx = codes.reshape(t, n_probes * L, S).astype(jnp.int32)
             qd = _qd_from_qlut(idx, qlut)
         qcand = jnp.broadcast_to(qc_probed[:, :, None],
@@ -1563,6 +1584,7 @@ def _search_impl(index: IvfPqIndex, queries: jax.Array, k: int,
 @partial(jax.jit, static_argnames=("k", "n_probes", "seg", "n_seg",
                                    "seg_chunk", "use_pallas", "select_impl",
                                    "select_recall", "use_segk"))
+@annotate(_SCAN)
 def _search_grouped(index: IvfPqIndex, queries: jax.Array, k: int,
                     n_probes: int, seg: int, n_seg: int, seg_chunk: int,
                     use_pallas: bool = False, filter_bits=None,
@@ -1598,13 +1620,15 @@ def _search_grouped(index: IvfPqIndex, queries: jax.Array, k: int,
 
     use_pallas = use_pallas and index.packed_recon is not None
 
-    _, probes = _coarse_probes(index, q_all, n_probes, ip_like)
+    with annotate(_COARSE):
+        _, probes = _coarse_probes(index, q_all, n_probes, ip_like)
     seg_list, seg_q, pair_seg, pair_slot = ic.segment_probes(
         probes, n_lists, seg, n_seg)
 
-    q_rot = jnp.matmul(q_all, index.rotation.T,
-                       precision=get_precision())     # [B, rot_dim]
-    q_sq = jnp.sum(q_rot * q_rot, axis=1)
+    with annotate(_LUT):
+        q_rot = jnp.matmul(q_all, index.rotation.T,
+                           precision=get_precision())     # [B, rot_dim]
+        q_sq = jnp.sum(q_rot * q_rot, axis=1)
     valid_full = index.packed_ids >= 0
     if filter_bits is not None:
         from raft_tpu.neighbors.sample_filter import passes
@@ -1760,6 +1784,7 @@ def _search_grouped(index: IvfPqIndex, queries: jax.Array, k: int,
 
 @partial(jax.jit, static_argnames=("k", "n_probes", "seg", "n_seg",
                                    "lut_dtype"))
+@annotate(_SCAN)
 def _search_lut_pallas(index: IvfPqIndex, queries: jax.Array, k: int,
                        n_probes: int, seg: int, n_seg: int,
                        filter_bits=None, lut_dtype: str = "float32"):
@@ -1770,7 +1795,7 @@ def _search_lut_pallas(index: IvfPqIndex, queries: jax.Array, k: int,
     neither the decoded-f32 lists, the one-hot operands, nor the
     [B, n_probes·L] candidate tables ever exist in HBM. The merged bins
     run through the shared :func:`_finish_candidates` epilogue, so
-    results cannot drift from the fused/staged paths' semantics.
+    results cannot drift from the per_query path's semantics.
 
     ``filter_bits`` streams INTO the kernel as a per-candidate packed
     mask (``sample_filter.list_filter_bytes`` over the same id table
@@ -1792,11 +1817,14 @@ def _search_lut_pallas(index: IvfPqIndex, queries: jax.Array, k: int,
     B = q_all.shape[0]
     ip_like = mt in (DistanceType.InnerProduct, DistanceType.CosineExpanded)
 
-    _, probes = _coarse_probes(index, q_all, n_probes, ip_like)
+    with annotate(_COARSE):
+        _, probes = _coarse_probes(index, q_all, n_probes, ip_like)
     seg_list, seg_q, pair_seg, pair_slot = ic.segment_probes(
         probes, index.n_lists, seg, n_seg)
-    q_rot = jnp.matmul(q_all, index.rotation.T, precision=get_precision())
-    q_sq = jnp.sum(q_rot * q_rot, axis=1)
+    with annotate(_LUT):
+        q_rot = jnp.matmul(q_all, index.rotation.T,
+                           precision=get_precision())
+        q_sq = jnp.sum(q_rot * q_rot, axis=1)
     qv_all = q_rot[jnp.clip(seg_q, 0, B - 1)]         # [n_seg, seg, rot]
 
     filter_bytes = None
@@ -1984,7 +2012,7 @@ def search(index, queries: jax.Array, k: int,
         params = SearchParams()
     if params.lut_dtype == "auto" and params.refine == "none":
         # one resolution point for the fp8-default policy: every scan
-        # tier below (LUT kernel, staged, grouped, per-query) and the
+        # tier below (LUT kernel, grouped, per-query) and the
         # sharded dispatch receive a concrete dtype. Refined searches
         # resolve at the _route_refined RE-ENTRY instead, where k is
         # the oversampled k_cand = k·refine_ratio — the selection
@@ -2008,15 +2036,6 @@ def search(index, queries: jax.Array, k: int,
     if params.refine != "none":
         return _route_refined(index, queries, k, params, filter_bitset,
                               dataset)
-    if (_obs_spans.stages_enabled() and _obs_spans._trace_clean()
-            and filter_bitset is None
-            and index.codebook_kind == "per_subspace"):
-        # observability stage mode: dispatch coarse_quantize / lut / scan
-        # as separate programs, each under a recording span. Never under
-        # an outer jax trace — the routing would be baked into the
-        # caller's jit cache and outlive obs.disable()
-        _count_scan_dispatch("staged")
-        return search_staged(index, queries, k, params)
     n_probes = min(params.n_probes, index.n_lists)
     B = queries.shape[0]
     mode = params.scan_mode
@@ -2187,119 +2206,6 @@ def search_resilient(index: IvfPqIndex, queries: jax.Array, k: int,
         {"params": params, "dataset": dataset},
         _degrade.standard_search_ladder(queries.shape[0], has_lut=True),
         site="ivf_pq.search", deadline=deadline)
-
-
-# ---------------------------------------------------------------------------
-# stage-decomposed search (observability mode — see raft_tpu.obs)
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("n_probes", "ip_like"))
-def _stage_coarse(index: IvfPqIndex, q_all: jax.Array, n_probes: int,
-                  ip_like: bool):
-    return _coarse_probes(index, q_all, n_probes, ip_like)
-
-
-@jax.jit
-def _stage_lut(index: IvfPqIndex, q_all: jax.Array):
-    """Staged stage 2 (per_subspace): rotate queries + build the
-    query-only LUT [m, S, K] in one batched MXU contraction."""
-    q_rot = jnp.matmul(q_all, index.rotation.T, precision=get_precision())
-    q_sub = q_rot.reshape(q_rot.shape[0], index.pq_dim, index.pq_len)
-    qlut = jnp.einsum("msp,skp->msk", q_sub, index.codebooks,
-                      precision=get_precision())
-    return q_rot, qlut
-
-
-@partial(jax.jit, static_argnames=("k", "n_probes", "query_tile"))
-def _stage_scan(index: IvfPqIndex, q_rot_all: jax.Array, qlut_all: jax.Array,
-                qc: jax.Array, probes: jax.Array, k: int, n_probes: int,
-                query_tile: int):
-    """Staged stage 3: gather candidates, LUT-sum ⟨q,d⟩, metric epilogue,
-    select — the per_query scan with the LUT precomputed by _stage_lut."""
-    mt = resolve_metric(index.metric)
-    m = q_rot_all.shape[0]
-    S, K, L = index.pq_dim, index.pq_book_size, index.max_list_size
-    q_sq_all = jnp.sum(q_rot_all * q_rot_all, axis=1)
-    qc_probed_all = jnp.take_along_axis(qc, probes, axis=1)
-    # same preemption as the fused path: an oversized one-hot operand
-    # feed faults the device (observed at C≈254k, S=64, K=256) — the
-    # diagnostic mode must not crash exactly the big runs it exists to
-    # diagnose, so scan via the recon cache when it exists and the
-    # one-hot would be dangerous
-    use_recon_dot = (index.packed_recon is not None
-                     and n_probes * L * S * K >= (1 << 28))
-
-    def scan_tile(args):
-        q_rot, qlut, qc_probed, probe, q_sq = args
-        t = q_rot.shape[0]
-        cand_ids = index.packed_ids[probe].reshape(t, n_probes * L)
-        cand_norms = index.packed_norms[probe].reshape(t, n_probes * L)
-        if use_recon_dot:
-            rows = index.packed_recon[probe].reshape(t, n_probes * L, -1)
-            dots = jnp.einsum("td,tcd->tc", q_rot,
-                              rows.astype(jnp.float32),
-                              precision=get_precision(),
-                              preferred_element_type=jnp.float32)
-        else:
-            codes_p = index.codes_chunk(probe.reshape(-1)).reshape(
-                t, n_probes, L, -1)
-            codes = index.unpack_codes(codes_p)
-            idx = codes.reshape(t, n_probes * L, S).astype(jnp.int32)
-            qd = _qd_from_qlut(idx, qlut)
-            dots = jnp.broadcast_to(
-                qc_probed[:, :, None],
-                (t, n_probes, L)).reshape(t, n_probes * L) + qd
-        return _finish_candidates(dots, cand_ids, cand_norms, q_sq, mt, k)
-
-    if m <= query_tile:
-        return scan_tile((q_rot_all, qlut_all, qc_probed_all, probes,
-                          q_sq_all))
-    n_tiles = -(-m // query_tile)
-    pad = n_tiles * query_tile - m
-    padded = tuple(
-        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        for a in (q_rot_all, qlut_all, qc_probed_all, probes, q_sq_all))
-    vals, ids = lax.map(scan_tile, tuple(
-        a.reshape((n_tiles, query_tile) + a.shape[1:]) for a in padded))
-    return vals.reshape(-1, k)[:m], ids.reshape(-1, k)[:m]
-
-
-def search_staged(index: IvfPqIndex, queries: jax.Array, k: int,
-                  params: Optional[SearchParams] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """Stage-decomposed search for observability: coarse_quantize / lut /
-    scan dispatch as separate programs, each under a recording
-    :func:`raft_tpu.obs.span` — with sync mode on, spans attribute
-    *device* time per stage (the fused :func:`search` cannot be timed
-    stage-wise from the host). Exact f32-LUT per_query semantics,
-    per_subspace codebooks only; results match ``search()``'s per_query
-    path. ``search()`` routes here when obs stage mode is enabled;
-    production paths never pay for the lost fusion."""
-    if params is None:
-        params = SearchParams()
-    expects(queries.ndim == 2 and queries.shape[1] == index.dim,
-            "queries must be [m, %d]", index.dim)
-    expects(index.codebook_kind == "per_subspace",
-            "search_staged supports per_subspace codebooks only")
-    mt = resolve_metric(index.metric)
-    ip_like = mt in (DistanceType.InnerProduct, DistanceType.CosineExpanded)
-    n_probes = min(params.n_probes, index.n_lists)
-    q_all = jnp.asarray(queries, jnp.float32)
-    if mt == DistanceType.CosineExpanded:
-        q_all = q_all / jnp.sqrt(jnp.maximum(
-            jnp.sum(q_all * q_all, -1, keepdims=True), 1e-12))
-    with span("coarse_quantize") as sp:
-        qc, probes = _stage_coarse(index, q_all, n_probes, ip_like)
-        sp.attach(qc, probes)
-    with span("lut") as sp:
-        q_rot, qlut = _stage_lut(index, q_all)
-        sp.attach(q_rot, qlut)
-    with span("scan") as sp:
-        out = _stage_scan(index, q_rot, qlut, qc, probes, k, n_probes,
-                          _fit_query_tile(params.query_tile, n_probes,
-                                          index))
-        sp.attach(out)
-    return out
 
 
 def _fit_query_tile(want: int, n_probes: int, index: IvfPqIndex) -> int:

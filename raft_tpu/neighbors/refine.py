@@ -40,10 +40,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from raft_tpu.core.errors import expects
-from raft_tpu.core.tracing import traced, span
+from raft_tpu.core.tracing import annotate, traced, span
 from raft_tpu.distance.types import DistanceType, resolve_metric
 from raft_tpu.matrix import select_k as _select_k
 from raft_tpu.obs import spans as _obs_spans
+from raft_tpu.ops import pallas_kernels as _pk
 from raft_tpu.robust import degrade as _degrade
 from raft_tpu.robust import faults as _faults
 from raft_tpu.utils.precision import get_precision
@@ -81,7 +82,14 @@ def _check_base_dim(base, queries) -> None:
             queries.shape[1])
 
 
+# Stage scope of the re-rank programs (op_name metadata only), whichever
+# index's search handed over the candidates; the fused Pallas re-rank
+# takes the same one.
+_REFINE = _pk.REFINE_SCOPE
+
+
 @partial(jax.jit, static_argnames=("k", "metric"))
+@annotate(_REFINE)
 def _refine_impl(dataset, queries, candidates, k: int, metric: str):
     safe_cand = jnp.maximum(candidates, 0)
     cand_rows = dataset[safe_cand].astype(jnp.float32)    # [m, C, d]
@@ -89,6 +97,7 @@ def _refine_impl(dataset, queries, candidates, k: int, metric: str):
 
 
 @partial(jax.jit, static_argnames=("k", "metric"))
+@annotate(_REFINE)
 def _refine_rows(cand_rows, queries, candidates, k: int, metric: str):
     mt = resolve_metric(metric)
     q = jnp.asarray(queries, jnp.float32)
@@ -120,6 +129,7 @@ def _refine_rows(cand_rows, queries, candidates, k: int, metric: str):
 
 
 @partial(jax.jit, static_argnames=("metric",))
+@annotate(_REFINE)
 def _gather_keys_to_dists(keys, ids, metric: str):
     """Kernel keys → reported distances: the gather-refine kernel emits
     minimized sort keys (l2: squared distance, ip: −score, cos: cosine
@@ -140,7 +150,6 @@ def _fused_refine_wanted(dataset, queries, candidates, k: int,
     accepts (``filtered`` adds the per-candidate bitset-word scratch to
     its VMEM model)."""
     from raft_tpu.neighbors import ivf_common as ic
-    from raft_tpu.ops import pallas_kernels as _pk
 
     if not isinstance(dataset, jax.Array) or dataset.ndim != 2:
         return False
@@ -166,8 +175,6 @@ def _fused_refine_wanted(dataset, queries, candidates, k: int,
 
 def _refine_fused(dataset, queries, candidates, k: int, mt: DistanceType,
                   filter_bits=None):
-    from raft_tpu.ops import pallas_kernels as _pk
-
     met = ("ip" if mt == DistanceType.InnerProduct
            else "cos" if mt == DistanceType.CosineExpanded else "l2")
     with span("fused_scan") as _sp:

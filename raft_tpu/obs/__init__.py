@@ -6,7 +6,8 @@ this package makes the same attribution available *in process*:
 - :mod:`raft_tpu.obs.metrics` — thread-safe counters/gauges/histograms
   with labels, ``snapshot()`` → dict, ``dump_jsonl`` sink;
 - :mod:`raft_tpu.obs.spans`   — ``span(name)`` stage timers (dotted
-  nesting, optional device-time sync), recorded into the registry;
+  nesting, optional device-time sync), recorded into the registry and,
+  while a profiler collects, annotated on its timeline;
 - :mod:`raft_tpu.obs.hbm`     — ``device.memory_stats()`` telemetry,
   sampled per local device;
 - :mod:`raft_tpu.obs.prof`    — compiled-program cost attribution
@@ -67,8 +68,8 @@ from raft_tpu.obs.spans import (  # noqa: F401
     env_tristate,
     events_enabled,
     registry,
+    profiling,
     span,
-    stages_enabled,
     sync_enabled,
 )
 from raft_tpu.obs import hbm  # noqa: F401

@@ -23,18 +23,27 @@ Semantics:
   themselves — a host timer inside a traced function would measure
   trace time once and nothing on cached calls, and blocking on tracers
   would be an error.
-- **Stage mode** (``enable(stages=True)``): hot paths that offer a
-  stage-decomposed variant (``ivf_pq.search`` → ``search_staged``)
-  route to it, trading fusion for per-stage attribution.
+- **On the profiler's timeline**: while a ``jax.profiler`` session
+  collects, every span also opens a ``TraceAnnotation`` named
+  ``raft_tpu.<dotted.name>``, whether recording is on or not, so its
+  interval lands on the host lines of the device trace beside the
+  device's ops (one check when no profiler collects). A span's timeline
+  event takes its start from ``time.time_ns()`` at enter — the realtime
+  clock the profiler's host events are kept on — so exported events
+  line up with the profiler's trace.
+- Device time per stage of a fused search program is not a span's
+  business: the programs name their stages with in-trace scopes
+  (:func:`raft_tpu.core.tracing.annotate`), read from a device trace.
 
-Env: ``RAFT_TPU_OBS=1`` enables at import; ``RAFT_TPU_OBS_SYNC=1``,
-``RAFT_TPU_OBS_STAGES=1`` and ``RAFT_TPU_OBS_EVENTS=1`` (timeline event
-recording into :mod:`raft_tpu.obs.trace`) add the respective modes.
+Env: ``RAFT_TPU_OBS=1`` enables at import; ``RAFT_TPU_OBS_SYNC=1`` and
+``RAFT_TPU_OBS_EVENTS=1`` (timeline event recording into
+:mod:`raft_tpu.obs.trace`) add the respective modes.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -43,12 +52,14 @@ from raft_tpu.obs import metrics as _metrics
 
 _enabled = False
 _sync = False
-_stages = False
 _hbm_sample = True
 _events = False
 _registry: Optional[_metrics.MetricsRegistry] = None
 
 _tls = threading.local()
+
+#: ``jax.profiler.TraceAnnotation``, bound once JAX is imported
+_annotation = None
 
 
 def _stack():
@@ -58,19 +69,17 @@ def _stack():
     return s
 
 
-def enable(sync: bool = False, stages: bool = False,
+def enable(sync: bool = False,
            registry: Optional[_metrics.MetricsRegistry] = None,
            hbm: bool = True, events: bool = False) -> None:
     """Turn span recording on. ``sync`` blocks on attached arrays at span
-    exit (device time); ``stages`` routes searches through their
-    stage-decomposed variants; ``registry`` overrides the global sink;
+    exit (device time); ``registry`` overrides the global sink;
     ``hbm`` samples HBM gauges at root-span exit; ``events``
     additionally appends one timeline event per span exit (plus HBM
     counter samples) into the :mod:`raft_tpu.obs.trace` ring buffer for
     Chrome-trace/Perfetto export."""
-    global _enabled, _sync, _stages, _registry, _hbm_sample, _events
+    global _enabled, _sync, _registry, _hbm_sample, _events
     _sync = bool(sync)
-    _stages = bool(stages)
     _registry = registry
     _hbm_sample = bool(hbm)
     _events = bool(events)
@@ -78,10 +87,9 @@ def enable(sync: bool = False, stages: bool = False,
 
 
 def disable() -> None:
-    global _enabled, _sync, _stages, _registry, _events
+    global _enabled, _sync, _registry, _events
     _enabled = False
     _sync = False
-    _stages = False
     _events = False
     _registry = None
 
@@ -90,12 +98,12 @@ def _state():
     """Snapshot the enable state (for save/restore around a temporary
     enable — e.g. the bench's diagnostic capture must not wipe a
     RAFT_TPU_OBS=1 enable the user installed at import)."""
-    return (_enabled, _sync, _stages, _registry, _hbm_sample, _events)
+    return (_enabled, _sync, _registry, _hbm_sample, _events)
 
 
 def _restore(state) -> None:
-    global _enabled, _sync, _stages, _registry, _hbm_sample, _events
-    _enabled, _sync, _stages, _registry, _hbm_sample, _events = state
+    global _enabled, _sync, _registry, _hbm_sample, _events
+    _enabled, _sync, _registry, _hbm_sample, _events = state
 
 
 def enabled() -> bool:
@@ -106,12 +114,21 @@ def sync_enabled() -> bool:
     return _enabled and _sync
 
 
-def stages_enabled() -> bool:
-    return _enabled and _stages
-
-
 def events_enabled() -> bool:
     return _enabled and _events
+
+
+def profiling() -> bool:
+    """True while a ``jax.profiler`` session collects host events. Never
+    imports JAX: with JAX not imported, no session can be collecting."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation.is_enabled()
 
 
 def registry() -> _metrics.MetricsRegistry:
@@ -174,23 +191,30 @@ def env_tristate(name: str, default: str = "auto") -> str:
     return default
 
 
+#: JAX's ``trace_state_clean``, looked up once (a failed lookup per
+#: span costs more than the rest of the span)
+_clean_fn = None
+
+
 def _trace_clean() -> bool:
-    """True outside any JAX trace (safe to time / block / reroute)."""
-    try:
-        import jax
+    """True outside any JAX trace (safe to time / block)."""
+    global _clean_fn
+    if _clean_fn is None:
+        try:
+            import jax
 
-        return jax.core.trace_state_clean()
-    except Exception:
-        pass
-    try:  # newer jax drops it from the public namespace
-        from jax._src import core as _jax_core
+            _clean_fn = jax.core.trace_state_clean
+        except Exception:
+            try:  # newer jax drops it from the public namespace
+                from jax._src import core as _jax_core
 
-        return _jax_core.trace_state_clean()
-    except Exception:
-        # unknown jax: assume we ARE under a trace — spans go quiet, but
-        # timing/blocking a tracer or baking the staged route into a
-        # caller's jit cache would be worse than missing samples
-        return False
+                _clean_fn = _jax_core.trace_state_clean
+            except Exception:
+                # unknown jax: assume we ARE under a trace — spans go
+                # quiet, but timing/blocking a tracer would be worse
+                # than missing samples
+                _clean_fn = lambda: False  # noqa: E731
+    return _clean_fn()
 
 
 class span:
@@ -203,17 +227,25 @@ class span:
     Arrays may also be passed at construction: ``span("scan", out)``.
     ``labels`` (and :meth:`annotate`) attach key/values that ride into
     the timeline event's ``args`` when event recording is on.
+    ``timeline=False`` opens no profiler annotation of the span's own,
+    for a caller that has opened one under the same name
+    (:func:`raft_tpu.core.tracing.traced`).
     """
 
-    __slots__ = ("name", "_arrays", "_t0", "_live", "_labels")
+    __slots__ = ("name", "_arrays", "_t0", "_wall_ns", "_live", "_open",
+                 "_labels", "_timeline", "_ann")
 
     def __init__(self, name: str, *arrays: Any,
-                 labels: Optional[dict] = None):
+                 labels: Optional[dict] = None, timeline: bool = True):
         self.name = name
         self._arrays = list(arrays)
         self._t0 = 0.0
-        self._live = False
+        self._wall_ns = 0
+        self._live = False      # recording into the registry
+        self._open = False      # on the name stack
         self._labels = labels
+        self._timeline = timeline
+        self._ann = None
 
     def attach(self, *arrays: Any) -> "span":
         """Register arrays (any pytrees) to block on at exit when sync
@@ -232,22 +264,32 @@ class span:
         return self
 
     def __enter__(self) -> "span":
-        if not _enabled or not _trace_clean():
+        profiled = profiling()
+        if not (_enabled or profiled) or not _trace_clean():
             return self
-        self._live = True
-        _stack().append(self.name)
-        self._t0 = time.perf_counter()
+        stack = _stack()
+        stack.append(self.name)
+        self._open = True
+        if profiled and self._timeline:
+            self._ann = _annotation("raft_tpu." + ".".join(stack))
+            self._ann.__enter__()
+        if _enabled:
+            self._live = True
+            # the event's start, on the realtime clock of the profiler's
+            # host events
+            self._wall_ns = time.time_ns()
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if not self._live:
+        if not self._open:
             return False
         stack = _stack()
         try:
             # a raising block yields a truncated duration (and in sync
             # mode one with no device time) — don't mix it into the
             # same series as successful samples
-            if exc_type is None:
+            if self._live and exc_type is None:
                 if _sync and self._arrays:
                     import jax
 
@@ -271,9 +313,7 @@ class span:
                     ctx = _trace.current_request()
                     if ctx is not None:
                         args = {**(args or {}), **ctx.event_labels()}
-                    # wall-clock begin reconstructed from the monotonic
-                    # duration: one clock read per exit, none per enter
-                    events.record_span(dotted, time.time() - dt, dt,
+                    events.record_span(dotted, self._wall_ns * 1e-9, dt,
                                        args=args)
                 # sample HBM only at ROOT-span exit: memory_stats() is a
                 # runtime call, and at a child-span exit every
@@ -284,13 +324,15 @@ class span:
 
                     _hbm.sample(reg, events=events)
         finally:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+                self._ann = None
             stack.pop()
-            self._live = False
+            self._open = self._live = False
             self._arrays = []
         return False
 
 
 if env_flag("RAFT_TPU_OBS"):  # pragma: no cover - env-driven
     enable(sync=env_flag("RAFT_TPU_OBS_SYNC"),
-           stages=env_flag("RAFT_TPU_OBS_STAGES"),
            events=env_flag("RAFT_TPU_OBS_EVENTS"))

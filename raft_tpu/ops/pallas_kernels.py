@@ -31,6 +31,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.tracing import annotate
 from raft_tpu.obs.spans import env_tristate as _env_tristate
 
 # Lane width constraint: last dim multiples of 128, sublanes of 8 (f32).
@@ -1059,6 +1060,9 @@ _GATHER_NBUF = 8
 # In-kernel running-buffer width (one lane tile); the k-round merge
 # extraction bounds serviceable k the same way _select_k_kernel does.
 GATHER_REFINE_MAX_K = 64
+# Stage scope of every re-rank program (op_name metadata only), this
+# kernel's and the XLA re-rank's in neighbors/refine.py.
+REFINE_SCOPE = "raft_tpu.refine"
 
 
 def _gather_refine_kernel(q_ref, cand_ref, cand_hbm, data_hbm,
@@ -1199,6 +1203,7 @@ def _gather_refine_kernel(q_ref, cand_ref, cand_hbm, data_hbm,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "interpret"))
+@annotate(REFINE_SCOPE)
 def gather_refine_topk(dataset: jax.Array, queries: jax.Array,
                        candidates: jax.Array, k: int, metric: str = "l2",
                        filter_bits=None,

@@ -719,6 +719,9 @@ def build_ivf_pq_distributed(dataset, params, mesh: Mesh,
         from raft_tpu.core import logging as _log
         _log.warn("distributed ivf_pq build: dropped %d overflow vectors "
                   "(raise list_size_cap_factor)", dropped)
+        from raft_tpu.neighbors.ivf_common import note_rows_dropped
+
+        note_rows_dropped("ivf_pq", dropped)
     return ShardedIvfPq(
         centers=centers, centers_rot=centers_rot, rotation=rotation,
         codebooks=codebooks, packed_codes=jnp.asarray(packed),
@@ -870,6 +873,9 @@ def build_ivf_flat_distributed(dataset, params, mesh: Mesh,
         from raft_tpu.core import logging as _log
         _log.warn("distributed ivf_flat build: dropped %d overflow "
                   "vectors (raise list_size_cap_factor)", dropped)
+        from raft_tpu.neighbors.ivf_common import note_rows_dropped
+
+        note_rows_dropped("ivf_flat", dropped)
     packed_j = jnp.asarray(packed)
     # norms from the PACKED table (pad slots 0) with the same reduction
     # shape as the single-host build — bit-parity by construction

@@ -1,6 +1,6 @@
 """Observability layer: metrics registry, span timers, HBM telemetry,
-staged search, and the no-overhead-when-disabled contract
-(ISSUE 1 acceptance; see docs/observability.md)."""
+and the no-overhead-when-disabled contract (see
+docs/observability.md)."""
 
 import json
 import threading
@@ -408,64 +408,6 @@ class TestSelectKDispatchCounter:
         counters = reg.snapshot()["counters"]
         assert any(n.startswith("select_k.dispatch{") for n in counters), \
             counters
-
-
-class TestStagedSearch:
-    def test_staged_matches_per_query(self, pq_index):
-        idx, q = pq_index
-        sp = ivf_pq.SearchParams(n_probes=8, scan_mode="per_query")
-        d0, i0 = ivf_pq.search(idx, q, 10, sp)
-        d1, i1 = ivf_pq.search_staged(idx, q, 10, sp)
-        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-        np.testing.assert_allclose(np.asarray(d0), np.asarray(d1),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_stage_mode_routes_search_and_records_stages(self, pq_index):
-        idx, q = pq_index
-        sp = ivf_pq.SearchParams(n_probes=8, scan_mode="per_query")
-        d0, i0 = ivf_pq.search(idx, q, 10, sp)
-        reg = MetricsRegistry()
-        obs.enable(sync=True, stages=True, registry=reg)
-        d1, i1 = ivf_pq.search(idx, q, 10, sp)
-        obs.disable()
-        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-        h = reg.snapshot()["histograms"]
-        for stage in ("span.ivf_pq.search.coarse_quantize",
-                      "span.ivf_pq.search.lut",
-                      "span.ivf_pq.search.scan",
-                      "span.ivf_pq.search"):
-            assert h[stage]["count"] == 1, stage
-            assert h[stage]["sum"] > 0
-
-    def test_stage_mode_not_baked_into_outer_jit(self, pq_index):
-        # regression: inside a user's jax.jit trace, stage mode must NOT
-        # route to search_staged — the staged path would be baked into
-        # the caller's jit cache and outlive obs.disable()
-        idx, q = pq_index
-        sp = ivf_pq.SearchParams(n_probes=8, scan_mode="per_query")
-        d0, i0 = ivf_pq.search(idx, q, 10, sp)
-        reg = MetricsRegistry()
-        obs.enable(sync=True, stages=True, registry=reg)
-        d1, i1 = jax.jit(lambda qq: ivf_pq.search(idx, qq, 10, sp))(q)
-        obs.disable()
-        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-        assert "span.ivf_pq.search.scan" not in reg.snapshot()["histograms"]
-
-    def test_staged_rejects_per_cluster(self, rng):
-        x = rng.random((600, 16), dtype=np.float32)
-        idx = ivf_pq.build(x, ivf_pq.IndexParams(
-            n_lists=8, pq_dim=8, codebook_kind="per_cluster", seed=0,
-            cache_reconstruction="never"))
-        from raft_tpu.core.errors import LogicError
-
-        with pytest.raises(LogicError):
-            ivf_pq.search_staged(idx, jnp.asarray(x[:4]), 5)
-        # ...but stage-mode search() still works (falls back to fused)
-        obs.enable(stages=True, hbm=False)
-        d, i = ivf_pq.search(idx, jnp.asarray(x[:4]), 5,
-                             ivf_pq.SearchParams(n_probes=4))
-        obs.disable()
-        assert np.asarray(i).shape == (4, 5)
 
 
 class TestNoOverheadWhenDisabled:

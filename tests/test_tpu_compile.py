@@ -7,7 +7,10 @@ all pass there and fail only on the chip. The TPU compiler is
 installed with JAX, and it compiles for a chip that is described and
 not attached, so these tests lower and compile each kernel for device
 0 of a described ``v5e:2x2`` (the ring merge for all four) and check
-that the kernel is in the compiled program. Nothing runs.
+that the kernel is in the compiled program. Nothing runs. The exact
+search's program at the benchmark's shapes is compiled here too, to
+check that the chip's compiler keeps every instruction that does work
+under a stage scope, the layout copies it inserts included.
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported: only the one test worker given this file loads
@@ -167,3 +170,86 @@ def test_sharded_build_labels_its_trainset_four_chips(topo, monkeypatch):
         _spec(rep, (1 << 16, DIM), jnp.float32),
         _spec(rep, (N_LISTS, DIM), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# -- stage scopes at the exact search's cells, on the chip's compiler ------
+
+def _scopes():
+    """The benchmark's stage map (``benchmark/scopes.py``)."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import scopes
+
+    return scopes
+
+
+def _working_stages(text, stages):
+    """``{instruction: stage}`` of the executed instructions that do
+    work."""
+    scopes = _scopes()
+    found = scopes.stage_map(text, stages)
+    return {ins.name: found[ins.name]
+            for ins in scopes.executed(scopes.parse(text))
+            if not ins.trivial}
+
+
+@pytest.mark.parametrize("batch", [BATCH, 10])
+def test_knn_stages_cover_the_chips_program(one_chip, monkeypatch, batch):
+    """``brute_force.knn`` over 1M×128 as the benchmark's cells call it:
+    every instruction that does work — the layout copies the chip's
+    compiler inserts too — maps to a stage, and at batch 10 the Pallas
+    select's call keeps the select's scope."""
+    from raft_tpu.neighbors import brute_force
+
+    scopes = _scopes()
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)  # dispatch as on a chip
+    jax.clear_caches()  # no CPU-dispatch trace of the same shapes
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    index = brute_force.BruteForceIndex(dataset=s((N, DIM), jnp.float32),
+                                        norms=s((N,), jnp.float32))
+    text = brute_force.knn.__wrapped__.lower(
+        index, s((batch, DIM), jnp.float32), K).compile().as_text()
+    jax.clear_caches()
+    stages = _working_stages(text, scopes.KNN_STAGES)
+    assert scopes.OTHER not in stages.values(), sorted(
+        n for n, st in stages.items() if st == scopes.OTHER)
+    if batch == BATCH:
+        assert set(stages.values()) == {"distance", "tile_cut", "merge",
+                                        "prepare"}
+        assert any(n.startswith("copy") and st == "tile_cut"
+                   for n, st in stages.items())
+    else:
+        assert stages["select_k_pallas.1"] == "select_k"
+        assert set(stages.values()) == {"distance", "select_k"}
+
+
+def test_ivf_scan_kernel_takes_the_scan_scope(one_chip, monkeypatch):
+    """IVF-Flat's grouped search through the segmented scan kernel: the
+    kernel's call runs under the scan's scope (a small index; the
+    scope, not the size, is what is checked)."""
+    from raft_tpu.neighbors import ivf_common as ic
+    from raft_tpu.neighbors import ivf_flat
+
+    scopes = _scopes()
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    n_lists, lsize, batch, n_probes = 64, 256, 256, 8
+    index = ivf_flat.IvfFlatIndex(
+        centers=s((n_lists, DIM), jnp.float32),
+        packed_data=s((n_lists, lsize, DIM), jnp.float32),
+        packed_ids=s((n_lists, lsize), jnp.int32),
+        packed_norms=s((n_lists, lsize), jnp.float32),
+        list_sizes=s((n_lists,), jnp.int32), metric="sqeuclidean")
+    n_seg = ic.n_segments(batch * n_probes, n_lists, SEG)
+    text = ivf_flat._search_grouped.lower(
+        index, s((batch, DIM), jnp.float32), K, n_probes, SEG, n_seg, 4,
+        use_segk=True, select_impl="approx").compile().as_text()
+    jax.clear_caches()
+    stages = _working_stages(text, {ivf_flat._COARSE: "coarse_quantize",
+                                    ivf_flat._SCAN: "scan"})
+    assert stages["segmented_scan_topk.1"] == "scan"
+    assert set(stages.values()) == {"coarse_quantize", "scan"}

@@ -164,7 +164,6 @@ latency/bandwidth trade and docs/observability.md for the byte model.
 | `segk` | `scan_select="approx"` + recon cache + VMEM fit (TPU); filtered shapes also pass `filtered_scan_mem_ok(slot_bytes=5)` | scalar-prefetch DMA kernel over bf16 recon rows | sentinel-masked id table: filtered slots become the `-1` invalid id BEFORE the kernel's bin pre-selection | `[n_seg, seg, 256]` bin tables (+ the masked `[n_lists, L]` id table when filtered) |
 | `pallas_lut` | `scan_select="pallas"`, or `"approx"` auto-upgraded for oversampled shapes (`n_probes ≥ 64` or `k ≥ 400`) with NO recon cache; needs `n_probes·256 ≥ k`; filtered shapes also pass `filtered_scan_mem_ok` (TPU) | fused LUT-scan over PACKED codes: in-kernel n-bit unpack, on-chip ADC Σ_s QLUT[s, code_s], 2-deep bin top-k | packed keep bits (`sample_filter.list_filter_bytes`, 1 bit/candidate) streamed beside the codes, unpacked in-kernel, masked to the ±inf/-1 sentinel BEFORE bin selection | `[n_seg, seg, 256]` bin tables (+ `[n_lists, ceil(L/8)]` filter bytes when filtered) |
 | `ring_lut_fused` | sharded (`mesh=`) non-refined search where the ring merge would run (see `parallel.merge`'s table) | the scan folded INTO the ring exchange — one persistent kernel per shard from packed codes to the merged top-k | per-shard byte slice (the replicated global bitset composed with the shard's global-id table) streamed per code tile, same sentinel epilogue | none: chunk candidates live in VMEM only (+ the per-shard filter bytes when filtered) |
-| `staged` | obs stage mode (`RAFT_TPU_OBS_STAGES=1`) | per-stage programs under recording spans | as per_query | as per_query |
 
 Since ISSUE 12, a `filter_bitset` is a streamed per-candidate mask in
 every tier, never a dispatch disqualifier: filtered dispatches count
