@@ -30,6 +30,8 @@ from raft_tpu.core import ids as _ids
 from raft_tpu.distance import pairwise_distance, resolve_metric, DistanceType, SELECT_MIN
 from raft_tpu.matrix import select_k as _select_k
 from raft_tpu.matrix.select_k import merge_parts
+from raft_tpu.obs import spans as _obs_spans
+from raft_tpu.ops import pallas_kernels as _pk
 from raft_tpu.utils.precision import get_precision
 
 # Max elements of one [query_tile, index_tile] distance block (~256 MB f32).
@@ -51,12 +53,19 @@ _PREPARE = "raft_tpu.brute_force.prepare"
 def _two_best_per_bin(dists: jax.Array, select_min: bool):
     """Per-tile candidate cut: the two best entries of each of 128
     STRIDED bins (position mod 128) with their in-tile positions —
-    [m, it] → ([m, 256], [m, 256]) in two vectorized min/argmin passes.
-    The same reduction the segmented IVF kernel applies in VMEM
-    (ops/pallas_kernels._segmented_scan_kernel), here in XLA for the
-    brute-force tile scan: it replaces a k-round extraction select with
-    work the VPU does in one sweep, and positions come from arithmetic
-    (argmin·128 + lane), never a gather."""
+    [m, it] → ([m, 256], [m, 256]), the best of each bin in the first
+    half. The same reduction the segmented IVF kernel applies in VMEM
+    (ops/pallas_kernels._segmented_scan_kernel): it replaces a k-round
+    extraction select with work the VPU does in one sweep, and
+    positions come from arithmetic (chunk·128 + lane), never a gather.
+    On a TPU it is one Pallas pass over the block in its native layout
+    (``pallas_kernels.bin_two_best``); elsewhere two vectorized
+    min/argmin passes in XLA, which the kernel matches bit for bit."""
+    if _pk._on_tpu():
+        _obs_spans.count_dispatch("brute_force.tile_cut", "pallas")
+        return _pk.bin_two_best(dists, select_min)
+    _obs_spans.count_fallback("brute_force.tile_cut", "off_tpu")
+    _obs_spans.count_dispatch("brute_force.tile_cut", "xla")
     m, it = dists.shape
     s = dists if select_min else -dists
     T = it // _BIN_LANES

@@ -1382,6 +1382,131 @@ def select_k_pallas(scores: jax.Array, k: int, select_min: bool = True,
 
 
 # ---------------------------------------------------------------------------
+# strided-bin cut: the two best of each of 128 strided bins of a wide row
+# ---------------------------------------------------------------------------
+
+# Bytes of one input block: double-buffered, plus the output blocks and
+# the running state, it stays inside the default scoped VMEM.
+_BIN_BLOCK_BYTES = 4 * 1024 * 1024
+_BIN_MAX_COLS = 2048
+_BIN_MAX_ROWS = 512
+
+
+def _bin_blocks(m: int, it: int) -> Tuple[int, int]:
+    """(row block, column block) of :func:`bin_two_best` over [m, it],
+    m ≥ 8 and it a lane multiple. The column block divides the row; the
+    row block is a multiple of 8 that divides m where such a divisor is
+    at least half the row cap, else the last row block is ragged."""
+    chunks = it // _LANES
+    tc = _LANES * max(c for c in range(1, _BIN_MAX_COLS // _LANES + 1)
+                      if chunks % c == 0)
+    cap = min(_BIN_MAX_ROWS, _BIN_BLOCK_BYTES // (4 * tc), m)
+    cap -= cap % _SUBLANES
+    tm = next((t for t in range(cap, cap // 2 - 1, -_SUBLANES)
+               if m % t == 0), cap)
+    return tm, tc
+
+
+def _bin_two_best_kernel(x_ref, vals_ref, pos_ref, b1_ref, p1_ref, b2_ref,
+                         p2_ref, *, select_min: bool, rows: int):
+    """Grid = (row blocks, column blocks); columns are the sequential
+    minor axis. Bin = column mod 128 is the lane, so the chunk ``t`` of
+    128 columns offers one candidate to every bin of a row, and the cut
+    is an elementwise running (best, second) per row and lane, kept in
+    scratch across column blocks. Strict comparisons over increasing
+    ``t`` keep the first of equal values as the best and a later equal
+    one as the second; a bin with nothing better than the initial
+    value keeps position 0 — XLA's argmin rules, so the result matches
+    the two min/argmin passes it replaces bit for bit. Rows go through
+    in groups of ``rows`` so the running state stays in registers while
+    the block's chunks stream past it."""
+    c = pl.program_id(1)
+    tm, tc = x_ref.shape
+    n_chunks = tc // _LANES
+    worst = jnp.inf if select_min else -jnp.inf
+    better = jnp.less if select_min else jnp.greater
+
+    @pl.when(c == 0)
+    def _init():
+        b1_ref[...] = jnp.full(b1_ref.shape, worst, jnp.float32)
+        b2_ref[...] = jnp.full(b2_ref.shape, worst, jnp.float32)
+        p1_ref[...] = jnp.zeros(p1_ref.shape, jnp.int32)
+        p2_ref[...] = jnp.zeros(p2_ref.shape, jnp.int32)
+
+    def group(g, carry):
+        rs = pl.ds(pl.multiple_of(g * rows, rows), rows)
+        b1, p1 = b1_ref[rs, :], p1_ref[rs, :]
+        b2, p2 = b2_ref[rs, :], p2_ref[rs, :]
+        for j in range(n_chunks):
+            v = x_ref[rs, pl.ds(j * _LANES, _LANES)]
+            t = c * n_chunks + j
+            first = better(v, b1)
+            second = better(v, b2)
+            b2 = jnp.where(first, b1, jnp.where(second, v, b2))
+            p2 = jnp.where(first, p1, jnp.where(second, t, p2))
+            b1 = jnp.where(first, v, b1)
+            p1 = jnp.where(first, t, p1)
+        b1_ref[rs, :], p1_ref[rs, :] = b1, p1
+        b2_ref[rs, :], p2_ref[rs, :] = b2, p2
+        return carry
+
+    jax.lax.fori_loop(0, tm // rows, group, 0)
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _out():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (tm, _LANES), 1)
+        for half, (b_ref, p_ref) in enumerate(((b1_ref, p1_ref),
+                                               (b2_ref, p2_ref))):
+            cols = pl.ds(half * _LANES, _LANES)
+            b = b_ref[...]
+            # a largest-first cut reports every infinite value as -inf
+            vals_ref[:, cols] = b if select_min else jnp.where(
+                jnp.isinf(b), -jnp.inf, b)
+            pos_ref[:, cols] = p_ref[...] * _LANES + lane
+
+
+@functools.partial(jax.jit, static_argnames=("select_min", "interpret"))
+def bin_two_best(dists: jax.Array, select_min: bool = True,
+                 interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """The two best entries of each of 128 STRIDED bins (bin = column
+    mod 128) of every row of ``dists`` [m, it], it a multiple of 128,
+    with their column positions: ([m, 256] values, [m, 256] positions),
+    the best of each bin in the first half and the second best in the
+    second. One pass that streams the block from HBM in its native
+    layout, where the bin is the lane: no layout copy of the block and
+    no second reduction over it. Matches the XLA two-pass cut
+    (``neighbors.brute_force._two_best_per_bin`` off the TPU) bit for
+    bit, ties and infinite bins included."""
+    m, it = dists.shape
+    if it % _LANES:
+        raise ValueError(f"row length {it} is not a multiple of {_LANES}")
+    x = _pad_to(dists, _SUBLANES, 0, 0.0) if m < _SUBLANES else dists
+    tm, tc = _bin_blocks(x.shape[0], it)
+    rows = next(r for r in (32, 16, 8) if tm % r == 0)
+    state = [pltpu.VMEM((tm, _LANES), dt)
+             for dt in (jnp.float32, jnp.int32, jnp.float32, jnp.int32)]
+    vals, pos = pl.pallas_call(
+        functools.partial(_bin_two_best_kernel, select_min=select_min,
+                          rows=rows),
+        grid=(pl.cdiv(x.shape[0], tm), it // tc),
+        in_specs=[pl.BlockSpec((tm, tc), lambda i, j: (i, j))],
+        out_specs=[
+            pl.BlockSpec((tm, 2 * _LANES), lambda i, j: (i, 0)),
+            pl.BlockSpec((tm, 2 * _LANES), lambda i, j: (i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((x.shape[0], 2 * _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((x.shape[0], 2 * _LANES), jnp.int32),
+        ],
+        scratch_shapes=state,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(x.astype(jnp.float32))
+    return vals[:m], pos[:m]
+
+
+# ---------------------------------------------------------------------------
 # ring top-k exchange: reduce-scatter-of-top-k across a mesh axis — each
 # device streams only its surviving [mc, k] block to its ring neighbor via
 # async remote DMA; the [n_dev, m, k] allgather buffer never exists

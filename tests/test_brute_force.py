@@ -2,6 +2,7 @@
 cpp/internal/raft_internal/neighbors/naive_knn.cuh:82 + recall thresholds
 in cpp/test/neighbors/ann_utils.cuh)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -102,3 +103,80 @@ def test_tiled_bins_path_matches_exact(rng, monkeypatch):
     np.testing.assert_allclose(np.asarray(vip),
                                -np.sort(-sip, axis=1)[:, :10],
                                rtol=1e-4, atol=1e-4)
+
+
+def _tiled_inputs(rng, metric):
+    """Two 16,384-row tiles (the tile's floor) at a small query batch,
+    the distance budget cut so that the scan tiles."""
+    from raft_tpu.neighbors import brute_force as bf
+
+    x = rng.random((20_000, 16), dtype=np.float32)
+    q = rng.random((24, 16), dtype=np.float32)
+    assert bf._choose_tiles(24, 20_000, 16)[1] < 20_000
+    return bf.build(jnp.asarray(x), metric=metric), jnp.asarray(q)
+
+
+@pytest.fixture
+def tile_cut_as_on_tpu(monkeypatch):
+    """The tile cut dispatches as on a TPU, its kernel in TPU interpret
+    mode; programs traced under it are dropped afterwards."""
+    from jax.experimental.pallas import tpu as pltpu
+    from raft_tpu.ops import pallas_kernels as pk
+
+    def on():
+        jax.clear_caches()
+        monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+        pltpu.set_tpu_interpret_mode()
+
+    try:
+        yield on
+    finally:
+        pltpu.set_tpu_interpret_mode(None)
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("metric", ["sqeuclidean", "inner_product"])
+def test_tiled_knn_same_with_the_kernel(rng, monkeypatch, tile_cut_as_on_tpu,
+                                        metric):
+    """The tiled scan returns the same ids and distances with the tile
+    cut's Pallas kernel as with the XLA cut."""
+    from raft_tpu.neighbors import brute_force as bf
+
+    monkeypatch.setattr(bf, "_TILE_BUDGET_ELEMS", 1 << 16)
+    idx, q = _tiled_inputs(rng, metric)
+    jax.clear_caches()
+    v_xla, i_xla = bf.knn(idx, q, 10)
+    tile_cut_as_on_tpu()
+    v_pk, i_pk = bf.knn(idx, q, 10)
+    np.testing.assert_array_equal(np.asarray(i_pk), np.asarray(i_xla))
+    np.testing.assert_array_equal(np.asarray(v_pk), np.asarray(v_xla))
+
+
+def test_tile_cut_counts_its_engine(rng, monkeypatch, tile_cut_as_on_tpu):
+    """Off the TPU a tiled trace counts the XLA cut and why; dispatched
+    as on a TPU it counts the Pallas kernel."""
+    from raft_tpu import obs
+    from raft_tpu.neighbors import brute_force as bf
+
+    monkeypatch.setattr(bf, "_TILE_BUDGET_ELEMS", 1 << 16)
+    idx, q = _tiled_inputs(rng, "sqeuclidean")
+
+    def counters():
+        reg = obs.MetricsRegistry()
+        obs.enable(registry=reg, hbm=False)
+        try:
+            bf.knn(idx, q, 10)[0].block_until_ready()
+        finally:
+            obs.disable()
+        return reg.snapshot()["counters"]
+
+    jax.clear_caches()
+    c = counters()
+    assert c.get("brute_force.tile_cut.fallback{reason=off_tpu}", 0) >= 1, c
+    assert c.get("brute_force.tile_cut.dispatch{impl=xla}", 0) >= 1, c
+    assert "brute_force.tile_cut.dispatch{impl=pallas}" not in c, c
+    tile_cut_as_on_tpu()
+    c = counters()
+    assert c.get("brute_force.tile_cut.dispatch{impl=pallas}", 0) >= 1, c
+    assert not any(key.startswith("brute_force.tile_cut.fallback")
+                   for key in c), c

@@ -63,6 +63,57 @@ def test_fused_dispatch_cpu_falls_back(rng):
     np.testing.assert_array_equal(np.asarray(i), np.argmin(full, 1))
 
 
+class TestBinTwoBest:
+    """bin_two_best (the exact search's per-tile bin cut on a TPU) in
+    interpret mode against the XLA two-pass cut it replaces: values
+    and positions equal, ties and infinite bins included."""
+
+    @staticmethod
+    def _planted(rng, m, it, select_min):
+        worst = np.inf if select_min else -np.inf
+        # five distinct values: equal entries share most bins
+        x = rng.integers(0, 5, size=(m, it)).astype(np.float32)
+        x[:, 3::128] = 2.0              # a bin whose entries are all equal
+        x[:, 9::128] = worst            # a bin of padding alone
+        x[:, 17::128][::3] = -worst     # the other infinity, on some rows
+        x[:, it - it // 8:] = worst     # padded tail columns
+        return x
+
+    @pytest.mark.parametrize("select_min", [True, False])
+    @pytest.mark.parametrize("it", [128, 1024, 16384])
+    @pytest.mark.parametrize("m", [1, 7, 200, 1000])
+    def test_matches_the_xla_cut(self, rng, m, it, select_min):
+        from raft_tpu.neighbors import brute_force as bf
+        from raft_tpu.ops import pallas_kernels as pk
+
+        assert not pk._on_tpu()  # the module's cut is the XLA one here
+        x = jnp.asarray(self._planted(rng, m, it, select_min))
+        v, pos = pk.bin_two_best(x, select_min, interpret=True)
+        want_v, want_pos = bf._two_best_per_bin(x, select_min)
+        assert v.shape == pos.shape == (m, 256)
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(want_v))
+        np.testing.assert_array_equal(np.asarray(pos), np.asarray(want_pos))
+
+    @pytest.mark.parametrize("m,it,want", [
+        (10_000, 16_384, (400, 2048)),   # the benchmark's tile: no pad
+        (1000, 16_384, (512, 2048)),     # no divisor near the cap: ragged
+        (8, 128 * 7, (8, 896)),
+        (4096, 128 * 17, (512, 128)),    # a prime count of chunks
+    ])
+    def test_blocks(self, m, it, want):
+        from raft_tpu.ops import pallas_kernels as pk
+
+        tm, tc = pk._bin_blocks(m, it)
+        assert (tm, tc) == want
+        assert tm % 8 == 0 and it % tc == 0 and tm * tc * 4 <= (4 << 20)
+
+    def test_row_must_be_lane_aligned(self):
+        from raft_tpu.ops import pallas_kernels as pk
+
+        with pytest.raises(ValueError):
+            pk.bin_two_best(jnp.zeros((8, 200)), True, interpret=True)
+
+
 class TestSegmentedScan:
     """segmented_scan_topk (interpret mode off-TPU) vs numpy reference:
     per-strided-bin mins (bin = position mod 128) of each segment's

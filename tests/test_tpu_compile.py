@@ -19,7 +19,9 @@ compiles — an entry written for a described chip cannot be read back
 without one.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,8 @@ N, DIM, N_LISTS, L, SEG = 1_000_000, 128, 1024, 1472, 128
 BATCH, N_PROBES, K = 10_000, 64, 10
 N_SEG = BATCH * N_PROBES // SEG + N_LISTS
 PQ_DIM, PQ_BITS = 64, 8
+# the exact search's index tile (brute_force._choose_tiles at batch 10,000)
+TILE = 16_384
 
 
 @pytest.fixture(scope="module")
@@ -124,6 +128,15 @@ def test_select_k_pallas(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("select_min", [True, False])
+def test_bin_two_best(one_chip, select_min):
+    """The exact search's per-tile bin cut over one batch-10,000 tile."""
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    text = _compiled_text(lambda x: pk.bin_two_best(x, select_min),
+                          s((BATCH, TILE), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
 def test_fused_l2_argmin(one_chip):
     """k-means assignment of a build chunk to the 1024 coarse centers."""
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
@@ -186,6 +199,10 @@ def _scopes():
     return scopes
 
 
+# a copy's result: name and f32 dimensions, whatever their bitcast shape
+_COPY = re.compile(r"%(copy[\w.\-]*) = f32\[([\d,]+)\]")
+
+
 def _working_stages(text, stages):
     """``{instruction: stage}`` of the executed instructions that do
     work."""
@@ -200,8 +217,10 @@ def _working_stages(text, stages):
 def test_knn_stages_cover_the_chips_program(one_chip, monkeypatch, batch):
     """``brute_force.knn`` over 1M×128 as the benchmark's cells call it:
     every instruction that does work — the layout copies the chip's
-    compiler inserts too — maps to a stage, and at batch 10 the Pallas
-    select's call keeps the select's scope."""
+    compiler inserts too — maps to a stage; at batch 10,000 the tile
+    cut is the bin kernel's call and no copy of the distance block
+    remains, and at batch 10 the Pallas select's call keeps the
+    select's scope."""
     from raft_tpu.neighbors import brute_force
 
     scopes = _scopes()
@@ -219,8 +238,16 @@ def test_knn_stages_cover_the_chips_program(one_chip, monkeypatch, batch):
     if batch == BATCH:
         assert set(stages.values()) == {"distance", "tile_cut", "merge",
                                         "prepare"}
-        assert any(n.startswith("copy") and st == "tile_cut"
-                   for n, st in stages.items())
+        # the tile cut is the Pallas kernel, reading the distance block
+        # as the contraction wrote it: no copy of the block is left
+        cut = [n for n, st in stages.items() if st == "tile_cut"]
+        assert [n for n in cut if n.startswith("bin_two_best")], cut
+        assert re.search(r"%bin_two_best[.\d]* = .* custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', text)
+        block = BATCH * TILE
+        copies = [m.group(0) for m in _COPY.finditer(text)
+                  if math.prod(map(int, m.group(2).split(","))) == block]
+        assert not copies, copies
     else:
         assert stages["select_k_pallas.1"] == "select_k"
         assert set(stages.values()) == {"distance", "select_k"}
