@@ -5,14 +5,18 @@ TPU-native counterpart of ``raft::cluster::kmeans_balanced``
 mesocluster hierarchy :758, adjust_centers balancing). Same two-level
 design, TPU-shaped execution:
 
-1. fit ~√k *mesoclusters* with plain Lloyd;
+1. fit ~√k *mesoclusters*;
 2. partition each mesocluster's rows into fine clusters (count ∝ meso
    size), fitting per-meso Lloyd on padded, weight-masked row blocks
    (static shapes per meso — the TPU version of the reference's
    variable-size mesocluster kernels);
-3. finish with joint Lloyd sweeps over all fine centers, re-seeding
-   under-populated clusters from the fattest clusters' far points each
-   sweep (the reference's ``adjust_centers`` balancing pass).
+3. finish with joint Lloyd sweeps over all fine centers.
+
+Every level's sweeps are balanced (the reference's ``adjust_centers``
+pass): after each update but the last, the smallest clusters are
+re-seeded by splitting the fattest (:func:`_split_fattest`), as many as
+are starved (under a quarter of the mean) or fat (over 1.75 times
+it).
 
 Balance matters doubly on TPU: IVF lists are padded blocks, so variance in
 list size is wasted HBM *and* wasted scan FLOPs.
@@ -58,71 +62,111 @@ def _maybe_normalize(x: jax.Array, metric: str) -> jax.Array:
     return x
 
 
-@partial(jax.jit, static_argnames=("n_clusters", "n_iters"))
-def _balanced_lloyd(x, w, c0, n_clusters: int, n_iters: int, key,
-                    split_iters=0):
-    """Lloyd sweeps with per-sweep re-seeding of starved clusters
-    (reference: adjust_centers, detail/kmeans_balanced.cuh).
+#: a cluster holding under this share of the mean mass is starved
+#: (reference: adjust_centers' balancing threshold)
+_STARVE_SHARE = 0.25
+#: a cluster holding over this multiple of the mean mass is fat
+_FAT_SHARE = 1.75
+#: how far apart a split sets the two halves' centers, as a share of the
+#: distance from the split cluster's center to the row that directs it
+_SPLIT_SCALE = 1e-3
 
-    ``split_iters`` (traced) picks the re-seed target per sweep: sweeps
-    ``i < split_iters`` re-seed at random far-ish rows *inside the
-    fattest clusters* (best cluster BALANCE — measured on clustered
-    100K×1024 data it cuts the max list from ~45× the mean to ~2×,
-    which is exactly what the padded-list IVF layout needs; the
-    deterministic farthest rows would be the boundary ring, which
-    leaves the dense core as one cluster); later sweeps re-seed at the
-    globally farthest points (best cluster QUALITY — the
-    kmeans++-flavored choice for codebook/meso fits). Traced rather
-    than static so both phases share ONE compiled program — one XLA
-    compilation instead of one per variant."""
+
+def _member_rows(labels, weights, n_clusters, key):
+    """A row of each non-empty cluster, drawn at random from ``key``:
+    the weighted row whose random draw is the cluster's largest, a
+    masked max over ``[rows, clusters]`` (no scatter)."""
+    u = jax.random.uniform(key, labels.shape, minval=0.5, maxval=1.0)
+    u = jnp.where(weights > 0, u, 0.0)
+    hit = labels[:, None] == jnp.arange(n_clusters)[None, :]
+    return jnp.argmax(jnp.where(hit, u[:, None], -1.0), axis=0)
+
+
+def _split_fattest(centers, counts, active, step, mean):
+    """Re-seed the smallest clusters by splitting the fattest (the
+    reference's adjust_centers moves a starved center toward a row of a
+    large cluster; this is FAISS's ``split_clusters`` form of it). As
+    many are re-seeded as there are starved clusters (under
+    ``_STARVE_SHARE`` of ``mean``, the mean mass) or fat ones (over
+    ``_FAT_SHARE`` of it), whichever is more, and at most half of them:
+    the r-th smallest cluster takes the r-th largest one's center, and
+    the two are set apart by twice the donor's ``step`` (per cluster: a
+    short step toward one of its rows), so the next assignment divides
+    the fat cluster's rows by the hyperplane through its center across
+    that row's direction, and the small one's rows go to their
+    next-nearest centers. A donor must hold at least ``mean``. Only
+    ``active`` clusters take part.
+
+    Re-seeding at a far or random single row instead leaves a center
+    that only its own row's neighbourhood reaches: on data of many
+    small clusters it stays starved, sweep after sweep. Every choice
+    here is a sort of the ``[k]`` counts, so both backends take the
+    same one. Returns ``(centers, re-seeded)``.
+    """
+    k, d = centers.shape
+    n_starved = jnp.sum(active & (counts < _STARVE_SHARE * mean))
+    n_fat = jnp.sum(active & (counts > _FAT_SHARE * mean))
+    ascending = jnp.argsort(jnp.where(active, counts, jnp.inf))
+    descending = jnp.argsort(jnp.where(active, -counts, jnp.inf))
+    rank = jnp.zeros((k,), jnp.int32).at[ascending].set(
+        jnp.arange(k, dtype=jnp.int32))              # 0 = smallest
+    donor = descending[rank]
+    n_take = jnp.minimum(jnp.maximum(n_starved, n_fat),
+                         jnp.sum(active) // 2)
+    take = (active & (rank < n_take)
+            & (counts[donor] >= mean) & (counts < counts[donor]))
+    step = jnp.where(take[:, None], step[donor], 0.0)
+    out = jnp.where(take[:, None], centers[donor] + step, centers)
+    out = out.at[donor].add(-step)                   # the donor's half
+    return out, jnp.sum(take.astype(jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("n_clusters", "n_iters"))
+def _balanced_lloyd(x, w, c0, n_clusters: int, n_iters: int, key):
+    """Lloyd sweeps that keep the clusters balanced: after each sweep's
+    update but the last, the smallest clusters are re-seeded by
+    splitting the fattest, as many as are starved or fat
+    (:func:`_split_fattest`). The last sweep only updates, so
+    each returned center is the mean of the rows it holds. Returns
+    ``(centers, clusters re-seeded over all sweeps)``."""
     xf = x.astype(jnp.float32)
     wf = w.astype(jnp.float32)
     total_w = jnp.maximum(jnp.sum(wf), 1e-12)
-    # a cluster is "starved" below this fraction of the average mass
-    starve_thresh = 0.25 * total_w / n_clusters
 
-    def body(i, centroids):
-        d2, labels = fused_l2_nn_argmin(xf, centroids)
-        new_c, counts = _update_centroids(xf, wf, labels, n_clusters, centroids)
-        starved = counts < starve_thresh
-        u = jax.random.uniform(jax.random.fold_in(key, i),
-                               (xf.shape[0],), minval=1e-6)
-        split_score = counts[labels] + u * d2 / (jnp.max(d2) + 1e-12)
-        far_score = jnp.where(
-            wf > 0, jnp.where(i < split_iters, split_score, d2), -jnp.inf)
-        # re-seed candidates need no exact order — the hardware approx
-        # top-k replaces a full [n] sort per sweep (measured ~20 s at
-        # n=2M, k=8192: it dominated billion-scale coarse training)
-        _, far_idx = lax.approx_max_k(far_score, n_clusters,
-                                      recall_target=0.9)
-        # rank starved clusters; the j-th starved cluster takes the j-th
-        # farthest point as its new center
-        starved_rank = jnp.cumsum(starved.astype(jnp.int32)) - 1
-        take_idx = far_idx[jnp.clip(starved_rank, 0, n_clusters - 1)]
-        reseeded = xf[take_idx]
-        new_c = jnp.where(starved[:, None], reseeded, new_c)
-        return new_c
+    def body(i, carry):
+        centroids, n_reseeded = carry
+        _, labels = fused_l2_nn_argmin(xf, centroids)
+        new_c, counts = _update_centroids(xf, wf, labels, n_clusters,
+                                          centroids)
+        member = _member_rows(labels, wf, n_clusters,
+                              jax.random.fold_in(key, i))
+        step = _SPLIT_SCALE * (xf[member] - new_c)
+        active = jnp.broadcast_to(i < n_iters - 1, counts.shape)
+        new_c, n = _split_fattest(new_c, counts, active, step,
+                                  total_w / n_clusters)
+        return new_c, n_reseeded + n
 
-    return lax.fori_loop(0, n_iters, body, c0.astype(jnp.float32))
+    return lax.fori_loop(0, n_iters, body,
+                         (c0.astype(jnp.float32), jnp.int32(0)))
 
 
 @jax.jit
-def _balanced_sweep_batched(xs, ws, cs, kmask):
+def _balanced_sweep_batched(xs, ws, cs, kmask, key, reseed):
     """One Lloyd sweep of every mesocluster's fine fit: padded row
     blocks ``xs [M, T, d]``, weight masks ``ws [M, T]`` (0 = pad),
     centers ``cs [M, k, d]``, active-center masks ``kmask [M, k]`` (a
     meso wanting fewer than ``k`` centers masks the rest — inactive
     slots are pinned far away so they never attract rows nor re-seed).
     One batched einsum assigns, a one-hot MXU contraction updates, and
-    starved clusters re-seed per meso — the batched twin of one
-    :func:`_balanced_lloyd` sweep."""
+    with ``reseed`` each meso's smallest clusters are re-seeded by
+    splitting its fattest (:func:`_split_fattest`) — the batched twin of
+    one :func:`_balanced_lloyd` sweep. Returns ``(centers, re-seeded)``."""
     xf = xs.astype(jnp.float32)
     wf = ws.astype(jnp.float32)
     km = kmask.astype(jnp.bool_)
     k = cs.shape[1]
     k_active = jnp.maximum(jnp.sum(km, axis=1).astype(jnp.float32), 1.0)
     total_w = jnp.maximum(jnp.sum(wf, axis=1), 1e-12)        # [M]
-    starve_thresh = 0.25 * total_w / k_active                # [M]
     x_sq = jnp.sum(xf * xf, axis=-1)                         # [M, T]
     FAR = jnp.float32(1e15)
 
@@ -132,44 +176,54 @@ def _balanced_sweep_batched(xs, ws, cs, kmask):
                    preferred_element_type=jnp.float32)
     d2 = jnp.maximum(x_sq[..., None] + c_sq[:, None, :] - 2.0 * g, 0.0)
     labels = jnp.argmin(d2, axis=-1)                         # [M, T]
-    dmin = jnp.min(d2, axis=-1)
     oh = jax.nn.one_hot(labels, k, dtype=jnp.float32) * wf[..., None]
     counts = jnp.sum(oh, axis=1)                             # [M, k]
     sums = jnp.einsum("mtk,mtd->mkd", oh, xf, precision=get_precision(),
                       preferred_element_type=jnp.float32)
     new_c = jnp.where(counts[..., None] > 0,
                       sums / jnp.maximum(counts[..., None], 1e-12), cs)
-    starved = (counts < starve_thresh[:, None]) & km
-    far_score = jnp.where(wf > 0, dmin, -jnp.inf)
-    _, far_idx = lax.top_k(far_score, k)                     # [M, k]
-    starved_rank = jnp.cumsum(starved.astype(jnp.int32), axis=1) - 1
-    take = jnp.take_along_axis(far_idx,
-                               jnp.clip(starved_rank, 0, k - 1), axis=1)
-    reseeded = jnp.take_along_axis(
-        xf, take[..., None].astype(jnp.int32), axis=1)       # [M, k, d]
-    return jnp.where(starved[..., None], reseeded, new_c)
+    keys = jax.random.split(key, cs.shape[0])
+    member = jax.vmap(_member_rows, in_axes=(0, 0, None, 0))(
+        labels, wf, k, keys)                                 # [M, k]
+    rows = jnp.take_along_axis(xf, member[..., None], axis=1)  # [M, k, d]
+    step = _SPLIT_SCALE * (rows - new_c)
+    new_c, n = jax.vmap(_split_fattest)(new_c, counts, km & reseed, step,
+                                        total_w / k_active)
+    return new_c, jnp.sum(n)
 
 
-def _balanced_lloyd_batched(xs, ws, c0s, kmask, n_iters: int):
+def _balanced_lloyd_batched(xs, ws, c0s, kmask, n_iters: int, key):
     """All mesoclusters' fine Lloyd fits: ``n_iters`` sweeps of
     :func:`_balanced_sweep_batched`, one compiled program for every
     meso (a per-meso loop would compile one program per distinct
-    (size, k)).
+    (size, k)); every sweep but the last re-seeds. Returns
+    ``(centers, clusters re-seeded)``, the count on the device.
 
     The sweeps are dispatched one program each, not chained in one
     ``fori_loop``: on a TPU v5e (JAX 0.9.0, libtpu 0.0.34) a program
-    holding two or more of these sweeps, looped or unrolled, computes
-    other centers than the same sweeps run one program at a time. On
-    200,000 hard-synthetic rows into 256 lists the chained fit's
-    largest list was 7,073 rows (mean 781), still 3,192 after the final
-    sweeps — more than the IVF spill could place. One sweep per program
-    matches the CPU sweep for sweep (labels, counts and re-seeds
-    equal). Dispatch is asynchronous, so the host never waits between
+    holding two or more of these sweeps, looped or unrolled, computed
+    other centers than the same sweeps run one program at a time.
+    Dispatch is asynchronous, so the host never waits between
     sweeps."""
     cs = c0s.astype(jnp.float32)
-    for _ in range(n_iters):
-        cs = _balanced_sweep_batched(xs, ws, cs, kmask)
-    return cs
+    total = jnp.int32(0)
+    for i in range(n_iters):
+        cs, n = _balanced_sweep_batched(xs, ws, cs, kmask,
+                                        jax.random.fold_in(key, i),
+                                        i < n_iters - 1)
+        total = total + n
+    return cs, total
+
+
+def _note_reseeded(level: str, n) -> None:
+    """Count the clusters a fit level re-seeded under the obs counter
+    ``kmeans_balanced.reseeded{level=...}``; recorded only when obs is
+    on (reading the count waits for the fit)."""
+    from raft_tpu.obs import spans as _obs_spans
+
+    if _obs_spans.enabled():
+        _obs_spans.registry().inc("kmeans_balanced.reseeded", float(n),
+                                  labels={"level": level})
 
 
 def build_clusters(
@@ -190,7 +244,9 @@ def build_clusters(
     w = jnp.ones((n,), jnp.float32) if sample_weights is None else sample_weights
     key = RngState(params.seed).key()
     c0 = init_random(key, xn, n_clusters)
-    centers = _balanced_lloyd(xn, w, c0, n_clusters, params.n_iters, key)
+    centers, n_re = _balanced_lloyd(xn, w, c0, n_clusters, params.n_iters,
+                                    key)
+    _note_reseeded("flat", n_re)
     centers = _maybe_normalize(centers, params.metric)
     _, labels = fused_l2_nn_argmin(xn, centers)
     sizes = jax.ops.segment_sum(jnp.ones_like(w), labels, num_segments=n_clusters)
@@ -240,13 +296,17 @@ def fit(  # graftlint: disable-fn=GL01
     if n_meso <= 1 or n_clusters <= 8:
         c0 = init_random(key, xn, n_clusters)
         w = jnp.ones((n,), jnp.float32)
-        centers = _balanced_lloyd(xn, w, c0, n_clusters, params.n_iters, key)
+        centers, n_re = _balanced_lloyd(xn, w, c0, n_clusters,
+                                        params.n_iters, key)
+        _note_reseeded("flat", n_re)
         return _maybe_normalize(centers, params.metric)
 
     # level 1: mesoclusters (reference: detail/kmeans_balanced.cuh:758)
     w = jnp.ones((n,), jnp.float32)
     meso_c0 = init_random(key, xn, n_meso)
-    meso_centers = _balanced_lloyd(xn, w, meso_c0, n_meso, params.n_iters, key)
+    meso_centers, n_re = _balanced_lloyd(xn, w, meso_c0, n_meso,
+                                         params.n_iters, key)
+    _note_reseeded("meso", n_re)
     _, meso_labels = fused_l2_nn_argmin(xn, meso_centers)
     meso_labels_h = np.asarray(meso_labels)
     sizes = np.bincount(meso_labels_h, minlength=n_meso)
@@ -298,8 +358,11 @@ def fit(  # graftlint: disable-fn=GL01
     c0s = jnp.take_along_axis(subs, jnp.asarray(pos)[..., None], axis=1)
     kmask_h = (np.arange(k_pad)[None, :]
                < k_active[:, None]).astype(np.float32)
-    cms = np.asarray(_balanced_lloyd_batched(
-        subs, masks, c0s, jnp.asarray(kmask_h), params.n_iters))
+    cms, n_re = _balanced_lloyd_batched(
+        subs, masks, c0s, jnp.asarray(kmask_h), params.n_iters,
+        jax.random.fold_in(key, 1))
+    cms = np.asarray(cms)
+    _note_reseeded("fine", n_re)
     fine_centers = [cms[m, :int(k_active[m])]
                     for m in range(n_meso) if sizes[m] > 0]
     centers = jnp.asarray(np.concatenate(fine_centers, axis=0))
@@ -308,12 +371,13 @@ def fit(  # graftlint: disable-fn=GL01
                             n_clusters - centers.shape[0])
         centers = jnp.concatenate([centers, extra], axis=0)
 
-    # final joint sweeps over the full data: fat-splitting sweeps drive
-    # list sizes toward the mean, then two plain quality sweeps settle
-    # the centers — one compiled program (split_iters is traced)
-    sweeps = max(2, params.n_iters // 4)
-    centers = _balanced_lloyd(xn, w, centers, n_clusters, sweeps + 2, key,
-                              split_iters=sweeps)
+    # final joint sweeps over the full data: the fine fits balanced each
+    # meso alone; these balance the lists across mesos, and the last
+    # sweep settles every center on the mean of its rows
+    sweeps = max(2, params.n_iters // 4) + 2
+    centers, n_re = _balanced_lloyd(xn, w, centers, n_clusters, sweeps,
+                                    jax.random.fold_in(key, 2))
+    _note_reseeded("joint", n_re)
     return _maybe_normalize(centers, params.metric)
 
 

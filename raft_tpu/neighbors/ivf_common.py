@@ -44,6 +44,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # Default segment size: one MXU-friendly block of queries per segment
@@ -61,6 +62,22 @@ def note_rows_dropped(index: str, n: int) -> None:
     if n and _obs_spans.enabled():
         _obs_spans.registry().inc("ivf.build.rows_dropped", float(n),
                                   labels={"index": index})
+
+
+def note_list_fill(index: str, list_sizes, capacity: int) -> None:
+    """Set the obs gauge ``ivf.build.list_fill{index=...}`` to the rows
+    an index holds over its padded slots (``n_lists * capacity``): the
+    share of every probed block's rows that is data, not padding.
+    Recorded only when obs is on (reading the sizes waits for the
+    build)."""
+    from raft_tpu.obs import spans as _obs_spans
+
+    if _obs_spans.enabled():
+        sizes = np.asarray(list_sizes)
+        _obs_spans.registry().set(
+            "ivf.build.list_fill",
+            float(sizes.sum()) / max(1, sizes.size * int(capacity)),
+            labels={"index": index})
 
 
 def sharded_dispatch(index, mesh, cls_name: str):

@@ -60,7 +60,11 @@ class IndexParams:
     kmeans_n_iters: int = 20
     kmeans_trainset_fraction: float = 0.5
     add_data_on_build: bool = True
-    list_size_cap_factor: float = 4.0  # max_list_size = factor * n/n_lists
+    # max_list_size = factor * n/n_lists, the rows past it dropped (or
+    # spilled, below); None: no cap, as the reference's variable-length
+    # lists — the padded capacity is the largest list, and every row is
+    # kept in the list of its nearest center
+    list_size_cap_factor: Optional[float] = 4.0
     # TPU-specific: cap padded capacity at the factor above and SPILL
     # overflow rows to their second-nearest list instead of dropping
     # them (ivf_common.spill_assignments) — every probe DMAs the padded
@@ -178,14 +182,17 @@ def _lane_round(size: int) -> int:
     return -(-size // 8) * 8
 
 
-def _fit_list_size(counts: np.ndarray, avg: int, cap_factor: float) -> int:
+def _fit_list_size(counts: np.ndarray, avg: int,
+                   cap_factor: Optional[float]) -> int:
     """Padded list capacity: the actual max list size, clamped by the cap
-    factor, rounded up lane-friendly (see _lane_round). Sizing to the
-    real histogram instead of the worst-case cap is a large scan-FLOP
-    saver — padding is wasted work on every probe."""
-    cap = max(8, int(avg * cap_factor))
+    factor (``None``: not clamped), rounded up lane-friendly (see
+    _lane_round). Sizing to the real histogram instead of the worst-case
+    cap is a large scan-FLOP saver — padding is wasted work on every
+    probe."""
     actual = int(counts.max()) if counts.size else 8
-    return _lane_round(min(cap, actual))
+    if cap_factor is None:
+        return _lane_round(actual)
+    return _lane_round(min(max(8, int(avg * cap_factor)), actual))
 
 
 @traced("raft_tpu.ivf_flat.build")
@@ -199,6 +206,9 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfFlatIn
     x = jnp.asarray(dataset)
     n, d = x.shape
     expects(params.n_lists <= n, "n_lists=%d > n=%d", params.n_lists, n)
+    expects(not (params.spill and params.list_size_cap_factor is None),
+            "spill=True needs a list_size_cap_factor to spill past "
+            "(None keeps every row in its nearest list, uncapped)")
 
     spherical = mt in (DistanceType.InnerProduct, DistanceType.CosineExpanded)
     km_params = KMeansBalancedParams(
@@ -224,7 +234,9 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfFlatIn
     avg = max(1, n // params.n_lists)
 
     if not params.add_data_on_build:
-        max_list_size = max(8, int(avg * params.list_size_cap_factor))
+        # no cap: the least capacity, which extend() grows as it needs
+        cap = params.list_size_cap_factor
+        max_list_size = 8 if cap is None else max(8, int(avg * cap))
         packed = jnp.zeros((params.n_lists, max_list_size, d), x.dtype)
         ids = jnp.full((params.n_lists, max_list_size), -1, jnp.int32)
         sizes = jnp.zeros((params.n_lists,), jnp.int32)
@@ -296,6 +308,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfFlatIn
     index = IvfFlatIndex(centers=centers, packed_data=packed,
                          packed_ids=ids, packed_norms=norms,
                          list_sizes=sizes, metric=mt.value)
+    ic.note_list_fill("ivf_flat", sizes, max_list_size)
     _istats.note_index_stats(index, name="ivf_flat.build", cheap=True)
     return index
 
@@ -383,8 +396,10 @@ def extend(index: IvfFlatIndex, new_vectors: jax.Array,  # graftlint: disable-fn
 
 # Stage scopes of the search programs (op_name metadata only): the whole
 # program is the scan of the probed lists with its merge, and probe
-# selection inside it is its own stage.
+# selection inside it is its own stage, as is the per-query path's
+# gather of the probed list blocks.
 _COARSE = "raft_tpu.ivf_flat.coarse_quantize"
+_GATHER = "raft_tpu.ivf_flat.gather"
 _SCAN = "raft_tpu.ivf_flat.scan"
 
 
@@ -423,8 +438,10 @@ def _search_impl(index: IvfFlatIndex, queries: jax.Array, k: int,
     def search_tile(args):
         q, probe = args  # [t, dim], [t, P]
         t = q.shape[0]
-        cand_data = index.packed_data[probe].astype(jnp.float32)  # [t,P,L,dim]
-        cand_ids = index.packed_ids[probe].reshape(t, n_probes * L)
+        with annotate(_GATHER):
+            cand_data = index.packed_data[probe].astype(jnp.float32)
+            cand_ids = index.packed_ids[probe].reshape(t, n_probes * L)
+            cand_norms = index.packed_norms[probe].reshape(t, n_probes * L)
         cand = cand_data.reshape(t, n_probes * L, index.dim)
         scores = jnp.einsum("td,tcd->tc", q, cand,
                             precision=get_precision(),
@@ -434,12 +451,11 @@ def _search_impl(index: IvfFlatIndex, queries: jax.Array, k: int,
             invalid_val = -jnp.inf
         elif mt == DistanceType.CosineExpanded:
             qn = jnp.sqrt(jnp.maximum(jnp.sum(q * q, 1), 1e-30))
-            cn = jnp.sqrt(jnp.maximum(
-                index.packed_norms[probe].reshape(t, n_probes * L), 1e-30))
+            cn = jnp.sqrt(jnp.maximum(cand_norms, 1e-30))
             dists = 1.0 - scores / (qn[:, None] * cn)
             invalid_val = jnp.inf
         else:
-            c_sq = index.packed_norms[probe].reshape(t, n_probes * L)
+            c_sq = cand_norms
             q_sq = jnp.sum(q * q, axis=1)
             dists = jnp.maximum(q_sq[:, None] + c_sq - 2.0 * scores, 0.0)
             if sqrt_out:
@@ -711,6 +727,17 @@ def search(index, queries: jax.Array, k: int,
     if params.refine != "none":
         return _route_refined(index, queries, k, params, filter_bitset,
                               dataset)
+    program, args, kwargs = _plan(index, queries, k, params, filter_bitset)
+    return program(*args, **kwargs)
+
+
+def _plan(index: IvfFlatIndex, queries: jax.Array, k: int,
+          params: SearchParams, filter_bitset=None):
+    """The single-chip search program for this call and what it is
+    called with: ``(program, args, kwargs)``, the program one of the
+    jitted :func:`_search_impl` (per query) and :func:`_search_grouped`
+    (grouped). ``program.lower(*args, **kwargs)`` gives the executable
+    a call of :func:`search` runs."""
     n_probes = min(params.n_probes, index.n_lists)
     B = queries.shape[0]
     mode = params.scan_mode
@@ -740,15 +767,13 @@ def search(index, queries: jax.Array, k: int,
                                                     S=seg))
             wants = (not approx) and _pk.pallas_grouped_wanted(
                 kk, L, index.dim, bq=seg)
-            return _search_grouped(index, queries, k, n_probes, seg,
-                                   n_seg, chunk, use_pallas=wants,
-                                   filter_bits=filter_bitset,
-                                   select_impl=params.scan_select,
-                                   select_recall=params.scan_recall,
-                                   use_segk=segk)
-    return _search_impl(index, queries, k, n_probes,
-                        _fit_query_tile(params.query_tile, n_probes, index),
-                        filter_bits=filter_bitset)
+            return _search_grouped, (index, queries, k, n_probes, seg,
+                                     n_seg, chunk), dict(
+                use_pallas=wants, filter_bits=filter_bitset,
+                select_impl=params.scan_select,
+                select_recall=params.scan_recall, use_segk=segk)
+    return _search_impl, (index, queries, k, n_probes, _fit_query_tile(
+        params.query_tile, n_probes, index)), dict(filter_bits=filter_bitset)
 
 
 @traced("raft_tpu.ivf_flat.search_resilient")

@@ -861,6 +861,7 @@ def build(dataset: jax.Array, params: Optional[IndexParams] = None) -> IvfPqInde
             recon = _build_recon_cache(index)
             _sp.attach(recon)
             index = index.replace(packed_recon=recon)
+    ic.note_list_fill("ivf_pq", sizes, max_list_size)
     _istats.note_index_stats(index, name="ivf_pq.build", cheap=True)
     return index
 

@@ -684,7 +684,11 @@ def search_ivf_pq(params: _pq.SearchParams, index: ShardedIvfPq,
 def build_ivf_flat(params: _flat.IndexParams, dataset: jax.Array, mesh: Mesh,
                    axis: str = "shard") -> ShardedIvfFlat:
     """Distributed IVF-Flat build: global coarse centers (psum Lloyd) +
-    per-shard device-side raw-vector packing."""
+    per-shard device-side raw-vector packing, into lists capped at
+    ``list_size_cap_factor`` times the per-shard mean."""
+    expects(params.list_size_cap_factor is not None,
+            "the sharded IVF-Flat build packs inside one program, so its "
+            "lists need a list_size_cap_factor (None is not supported)")
     mt = resolve_metric(params.metric)
     x = jnp.asarray(dataset, jnp.float32)
     n, dim = x.shape

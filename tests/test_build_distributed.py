@@ -129,6 +129,20 @@ class TestDistributedBuildParity:
         _assert_identical(asm, single)
         assert index_sha16(asm) == index_sha16(single)
 
+    def test_ivf_flat_uncapped_bit_identical_to_build(self, mesh, data):
+        """No list cap: the distributed build takes None as the
+        single-host build does (capacity the largest list, every row
+        kept)."""
+        params = ivf_flat.IndexParams(n_lists=8, kmeans_n_iters=4, seed=1,
+                                      list_size_cap_factor=None)
+        sharded = ivf_flat.build_distributed(data, params, mesh=mesh,
+                                             chunk_rows=CHUNK)
+        single = ivf_flat.build(jnp.asarray(data), params)
+        _assert_identical(assemble_ivf_flat(sharded), single)
+        assert single.size == data.shape[0]
+        assert single.max_list_size == ivf_flat._lane_round(
+            int(np.asarray(single.list_sizes).max()))
+
     def test_search_consumes_per_shard_output_directly(self, mesh, data):
         """ISSUE 13 (c): the per-shard output IS a ShardedIvfPq — the
         PR-8 searcher takes it with no conversion, through both the

@@ -197,3 +197,87 @@ def test_balanced_fit_no_drop_warning_on_blobs():
     finally:
         rlog.set_callback(None)
     assert not any("level-2 sampling dropped" in m for m in msgs), msgs
+
+
+def _many_small_clusters(seed, n=20_000, dim=64, rows_per_cluster=24):
+    """The benchmark's hard_clusters design at the tests' size: n / 24
+    Gaussian clusters of equal expected size, each spread 0.45 of its
+    nearest other center's distance, in high dimension, where plain
+    Lloyd from random rows leaves many lists near empty."""
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(size=(n // rows_per_cluster, dim))
+    sq = (centers ** 2).sum(1)
+    d2 = sq[:, None] + sq[None, :] - 2.0 * centers @ centers.T
+    np.fill_diagonal(d2, np.inf)
+    scale = 0.45 * np.sqrt(d2.min(1)) / np.sqrt(dim)
+    pick = rng.integers(0, centers.shape[0], n)
+    return (centers[pick] + scale[pick, None]
+            * rng.normal(size=(n, dim))).astype(np.float32)
+
+
+@pytest.mark.parametrize("seed", [304, 313, 7])
+def test_balanced_fit_bounds(seed):
+    """Every list of the hierarchical fit holds between a tenth of the
+    mean and twice it, on data of many small clusters."""
+    from raft_tpu.cluster import kmeans_balanced
+    from raft_tpu.cluster.kmeans_balanced import KMeansBalancedParams
+
+    x = jnp.asarray(_many_small_clusters(seed))
+    n_lists = 128
+    centers = kmeans_balanced.fit(x, n_lists, KMeansBalancedParams())
+    sizes = np.bincount(np.asarray(kmeans_balanced.predict(centers, x)),
+                        minlength=n_lists)
+    mean = x.shape[0] / n_lists
+    assert sizes.max() <= 2.0 * mean, sizes.max() / mean
+    assert sizes.min() >= 0.1 * mean, sizes.min() / mean
+
+
+def test_fit_counts_reseeded():
+    """``kmeans_balanced.reseeded{level=}``: the flat fit's count is the
+    number of clusters its sweeps re-seeded, and the hierarchical fit
+    counts each level."""
+    from raft_tpu import obs
+    from raft_tpu.cluster import kmeans_balanced as kb
+    from raft_tpu.cluster.kmeans import init_random
+    from raft_tpu.obs.metrics import MetricsRegistry
+    from raft_tpu.random.rng import RngState
+
+    x = jnp.asarray(_many_small_clusters(5, n=4000, dim=32))
+    params = kb.KMeansBalancedParams(n_iters=10, seed=3)
+    reg = MetricsRegistry()
+    obs.enable(registry=reg, hbm=False)
+    try:
+        kb.fit(x, 8, params)
+        kb.fit(x, 64, params)
+    finally:
+        obs.disable()
+    counters = reg.snapshot()["counters"]
+    key = RngState(params.seed).key()
+    _, want = kb._balanced_lloyd(x, jnp.ones((x.shape[0],)),
+                                 init_random(key, x, 8), 8, params.n_iters,
+                                 key)
+    assert counters["kmeans_balanced.reseeded{level=flat}"] == int(want)
+    for level in ("meso", "fine", "joint"):
+        assert f"kmeans_balanced.reseeded{{level={level}}}" in counters
+    assert counters["kmeans_balanced.reseeded{level=fine}"] > 0
+
+
+def test_split_fattest_splits_the_largest_for_the_smallest():
+    """Three clusters are starved (under a quarter of the mean 100) and
+    at most half of the five are re-seeded: the two smallest take the
+    two largest ones' centers, each pair set apart by twice the donor's
+    step; the rest stay put."""
+    from raft_tpu.cluster.kmeans_balanced import _split_fattest
+
+    centers = jnp.arange(5 * 3, dtype=jnp.float32).reshape(5, 3)
+    counts = jnp.array([1.0, 400.0, 20.0, 2.0, 177.0])
+    step = jnp.full((5, 3), 0.25, jnp.float32) * jnp.arange(1, 6)[:, None]
+    out, n = _split_fattest(centers, counts, jnp.ones(5, bool), step,
+                            jnp.float32(100.0))
+    out, c, step = np.asarray(out), np.asarray(centers), np.asarray(step)
+    assert int(n) == 2
+    # the smallest (0) splits the largest (1); the next (3) the next (4)
+    for small, big in ((0, 1), (3, 4)):
+        np.testing.assert_allclose(out[small], c[big] + step[big])
+        np.testing.assert_allclose(out[big], c[big] - step[big])
+    np.testing.assert_array_equal(out[2], c[2])

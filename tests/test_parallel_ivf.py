@@ -292,6 +292,16 @@ class TestShardedFusedPipeline:
             search_ivf_pq(sp, sharded, jnp.asarray(dataset[:8]), 5, mesh)
 
 
+def test_sharded_ivf_flat_build_refuses_no_cap(mesh, data):
+    """The sharded build packs its lists inside one program, at a
+    capacity set by the cap: with no cap it refuses, naming it."""
+    dataset, _ = data
+    params = ivf_flat.IndexParams(n_lists=16, kmeans_n_iters=2,
+                                  list_size_cap_factor=None)
+    with pytest.raises(Exception, match="list_size_cap_factor"):
+        build_ivf_flat(params, jnp.asarray(dataset[:512]), mesh)
+
+
 class TestCollectiveSchedule:
     """Sharded IVF search programs under the collective-schedule checker
     (raft_tpu.obs.sanitize) — the merge's cross-shard gathers must form

@@ -143,16 +143,17 @@ def ivf_indexes():
 
 
 # scope name -> stage of the IVF programs and of the re-rank, from the
-# programs' own names (the benchmark reads no IVF cell yet)
+# programs' own names (the benchmark's IVF-Flat table is
+# benchmark/ivf_flat_stages.STAGES, which test_ivf_flat_stages checks)
 IVF_FLAT_STAGES = {ivf_flat._COARSE: "coarse_quantize",
-                   ivf_flat._SCAN: "scan"}
+                   ivf_flat._GATHER: "gather", ivf_flat._SCAN: "scan"}
 IVF_PQ_STAGES = {ivf_pq._COARSE: "coarse_quantize", ivf_pq._LUT: "lut",
                  ivf_pq._SCAN: "scan"}
 REFINE_STAGES = {refine._REFINE: "refine"}
 
 IVF_PROGRAMS = {
     "ivf_flat.per_query": ("flat", "per_query", IVF_FLAT_STAGES,
-                           {"coarse_quantize", "scan"}),
+                           {"coarse_quantize", "gather", "scan"}),
     "ivf_flat.grouped": ("flat", "grouped", IVF_FLAT_STAGES,
                          {"coarse_quantize", "scan"}),
     "ivf_pq.per_query": ("pq", "per_query", IVF_PQ_STAGES,

@@ -146,6 +146,43 @@ def test_fused_l2_argmin(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_balanced_fit_sweeps_contract_at_highest(one_chip, monkeypatch):
+    """The coarse fit at hard-1M's shapes: its batched level-2 sweep
+    (500,000 training rows in 32 mesoclusters of at most 31,256 rows, 64
+    fine centers each) and its joint sweeps over 1,024 centers (over a
+    chunk of 65,536 rows; the assignment in the Pallas argmin, as a TPU
+    dispatches it). Every
+    contraction the chip's compiler emits keeps HIGHEST: a one-pass bf16
+    assignment of rows to near-equidistant centers would flip labels."""
+    import jax.random as jr
+
+    from raft_tpu.cluster import kmeans_balanced as kb
+
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    key = jax.eval_shape(lambda: jr.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    m, t, k, n = 32, 31_256, 64, 1 << 16
+    texts = [
+        kb._balanced_sweep_batched.lower(
+            s((m, t, DIM), jnp.float32), s((m, t), jnp.float32),
+            s((m, k, DIM), jnp.float32), s((m, k), jnp.float32), key,
+            s((), jnp.bool_)).compile().as_text(),
+        kb._balanced_lloyd.lower(
+            s((n, DIM), jnp.float32), s((n,), jnp.float32),
+            s((N_LISTS, DIM), jnp.float32), N_LISTS, 7, key
+        ).compile().as_text()]
+    jax.clear_caches()
+    assert "tpu_custom_call" in texts[1]          # the Pallas argmin
+    for text in texts:
+        dots = [line for line in text.splitlines()
+                if re.search(r" (convolution|dot)\(", line)]
+        assert dots
+        for line in dots:
+            assert "operand_precision={highest,highest}" in line, line
+
+
 def test_ring_topk_merge_four_chips(topo):
     """The ring merge's remote DMAs and semaphores on a 4-chip mesh at
     the largest query batch its VMEM gate admits there."""
